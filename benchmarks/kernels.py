@@ -1,7 +1,8 @@
 """Kernel micro-benchmarks: lsh_hash / bucket-core / pairwise / attention
-wall time (jnp ref path on CPU; the Pallas kernels target TPU and are
-validated in interpret mode) + dynamic-update throughput across the three
-inner engines (sequential dict, batched dict, SoA vectorised)."""
+wall time on whatever platform JAX runs (``repro.kernels.ops`` picks the
+Pallas hash kernel on a TPU and the jnp reference elsewhere) + dynamic-
+update throughput across the three inner engines (sequential dict, batched
+dict, SoA vectorised)."""
 
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import numpy as np
 
 from repro.api import ClusterConfig, build_index
 from repro.data import blobs
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 RESULTS = Path(__file__).resolve().parent.parent / "results"
 
@@ -48,7 +49,7 @@ def run(smoke: bool = False):
         x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
         eta = jnp.asarray(rng.uniform(0, 1.5, t), jnp.float32)
         mix = jnp.asarray(rng.integers(1, 2**31 - 1, (2, t, d)), jnp.int32)
-        dt = _time(lambda a, b, c: ops.lsh_hash(a, b, c, inv_cell=1 / 1.5, impl="ref"),
+        dt = _time(lambda a, b, c: ops.lsh_hash(a, b, c, inv_cell=1 / 1.5),
                    x, eta, mix)
         rows.append({"bench": f"lsh_hash n={n}", "us_per_call": dt * 1e6,
                      "derived": f"{n / dt / 1e6:.1f} Mpoints/s"})
@@ -57,27 +58,17 @@ def run(smoke: bool = False):
     n, t, nb = (4_000, 8, 512) if smoke else (65_536, 8, 4_096)
     slots = jnp.asarray(rng.integers(0, nb, (n, t)), jnp.int32)
     sizes = jnp.asarray(rng.integers(0, 20, nb), jnp.int32)
-    impls = [("ref", slots, sizes)]
-    if not smoke:
-        # interpret mode is slow; bench it on a smaller tile
-        si = jnp.asarray(rng.integers(0, nb, (4_096, t)), jnp.int32)
-        impls.append(("pallas_interpret", si, sizes))
-    for impl, sl, sz in impls:
-        ni = int(sl.shape[0])
-        dt = _time(lambda a, b: ops.bucket_core_stats(a, b, k=10, impl=impl),
-                   sl, sz)
-        rows.append({"bench": f"bucket_core_stats[{impl}] n={ni}",
-                     "us_per_call": dt * 1e6,
-                     "derived": f"{ni / dt / 1e6:.1f} Mpoints/s"})
-        dt = _time(lambda a: ops.slot_counts(a, n_slots=nb, impl=impl), sl)
-        rows.append({"bench": f"slot_counts[{impl}] n={ni}",
-                     "us_per_call": dt * 1e6,
-                     "derived": f"{ni * t / dt / 1e6:.1f} Mupdates/s"})
+    dt = _time(lambda a, b: ops.bucket_core_stats(a, b, k=10), slots, sizes)
+    rows.append({"bench": f"bucket_core_stats n={n}", "us_per_call": dt * 1e6,
+                 "derived": f"{n / dt / 1e6:.1f} Mpoints/s"})
+    dt = _time(lambda a: ops.slot_counts(a, n_slots=nb), slots)
+    rows.append({"bench": f"slot_counts n={n}", "us_per_call": dt * 1e6,
+                 "derived": f"{n * t / dt / 1e6:.1f} Mupdates/s"})
 
     # pairwise counts
     for n, d in [(1_000 if smoke else 4_000, 20)]:
         x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-        dt = _time(lambda a: ops.eps_neighbor_counts(a, eps=0.75, impl="ref"), x)
+        dt = _time(lambda a: ref.eps_neighbor_counts(a, 0.75), x)
         rows.append({"bench": f"pairwise n={n}", "us_per_call": dt * 1e6,
                      "derived": f"{2 * n * n * d / dt / 1e9:.1f} GFLOP/s"})
 

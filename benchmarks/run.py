@@ -37,6 +37,9 @@ def main(argv=None) -> None:
                          "(backend=sharded; any other backend becomes the "
                          "inner engine)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     csv_rows = []
 

@@ -7,12 +7,14 @@ import argparse
 import numpy as np
 
 from repro.api import ClusterConfig, available_backends, build_index
+from repro.compile_cache import enable_compile_cache
 from repro.core import adjusted_rand_index
 from repro.data import blobs
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--backend", default="dynamic", choices=available_backends())
 args = ap.parse_args()
+enable_compile_cache()
 
 # 2000 points from 5 Gaussian blobs, streamed one at a time
 X, y = blobs(n=2000, d=5, n_clusters=5, cluster_std=0.15, seed=0)

@@ -16,6 +16,7 @@ import time
 import numpy as np
 
 from repro.api import ClusterConfig, available_backends, build_index
+from repro.compile_cache import enable_compile_cache
 from repro.core import adjusted_rand_index
 from repro.data import blobs
 
@@ -30,6 +31,7 @@ ap.add_argument("--sample-rate", type=float, default=0.2,
                 help="sampled-core fraction for --backend approx/tiered "
                      "(ignored by the exact engines)")
 args = ap.parse_args()
+enable_compile_cache()
 
 n, d, batch = 12000, 8, 1000
 X, y = blobs(n=n, d=d, n_clusters=8, cluster_std=0.2, seed=3)

@@ -8,9 +8,11 @@ for the ~124M-param preset (12 layers x d_model 768, vocab 32k).
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--full-100m", action="store_true")

@@ -5,9 +5,9 @@ key                engine
 =================  ==================================================
 ``dynamic``        DynamicDBSCAN — the paper's Alg. 2 (exact host keys)
 ``batched``        BatchedDynamicDBSCAN — batch hashing on host (mixed keys)
-``batched-device`` BatchedDynamicDBSCAN(use_device=True) — Pallas/ref kernel
+``batched-device`` BatchedDynamicDBSCAN(use_device=True) — device hashing
 ``soa``            SoADynamicDBSCAN — vectorised structure-of-arrays core
-``soa-device``     SoADynamicDBSCAN(use_device=True) — bucket_ops kernels
+``soa-device``     SoADynamicDBSCAN(use_device=True) — device hash/stats
 ``approx``         SampledCoreDBSCAN — DBSCAN++-style sampled cores
 ``emz-static``     EMZ recompute-per-query baseline (Esfandiari et al.)
 ``naive``          exact Algorithm-1 DBSCAN recompute-per-query baseline
@@ -386,8 +386,8 @@ def _build_batched(cfg: ClusterConfig) -> ClusterIndex:
 
 @register_backend("batched-device")
 def _build_batched_device(cfg: ClusterConfig) -> ClusterIndex:
-    # device hashing through repro.kernels.ops (Pallas on TPU, jnp ref on
-    # CPU — selected by REPRO_KERNELS, see kernels/ops.py)
+    # device hashing through repro.kernels.ops: the platform picks the
+    # Pallas kernel (TPU) or the jitted jnp reference (elsewhere)
     return _dynamic_engine(cfg, BatchedDynamicDBSCAN, use_device=True)
 
 
@@ -401,8 +401,9 @@ def _build_soa(cfg: ClusterConfig) -> ClusterIndex:
 
 @register_backend("soa-device")
 def _build_soa_device(cfg: ClusterConfig) -> ClusterIndex:
-    # bucket/support/core passes through repro.kernels.ops (Pallas on
-    # TPU, jnp ref on CPU — selected by REPRO_KERNELS, see kernels/ops.py)
+    # hash, occupancy and support passes through repro.kernels.ops: the
+    # platform picks the Pallas hash kernel (TPU) or the jitted jnp
+    # reference (elsewhere); occupancy/support are jitted XLA programs
     return SoAIndex(cfg, SoADynamicDBSCAN(
         cfg.d, cfg.k, cfg.t, cfg.eps, seed=cfg.seed,
         attach_orphans=cfg.attach_orphans, repair=cfg.repair,
@@ -414,8 +415,7 @@ def _build_approx(cfg: ClusterConfig) -> ClusterIndex:
     return ApproxIndex(cfg, SampledCoreDBSCAN(
         cfg.d, cfg.k, cfg.t, cfg.eps, seed=cfg.seed,
         attach_orphans=cfg.attach_orphans, repair=cfg.repair,
-        use_device=False, sample_rate=cfg.sample_rate,
-        approx_seed=cfg.approx_seed))
+        sample_rate=cfg.sample_rate, approx_seed=cfg.approx_seed))
 
 
 @register_backend("tiered")

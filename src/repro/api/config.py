@@ -11,6 +11,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
+# backends whose engine runs device programs: a process that builds one
+# opens the accelerator, which belongs to one process at a time
+DEVICE_BACKENDS = ("soa-device", "batched-device")
+
 
 @dataclasses.dataclass(frozen=True)
 class ClusterConfig:
@@ -87,6 +91,14 @@ class ClusterConfig:
             raise ValueError(
                 f"unknown transport {self.transport!r} "
                 "(expected 'local', 'process' or 'tcp')"
+            )
+        if (self.backend == "sharded" and self.transport != "local"
+                and self.inner_backend in DEVICE_BACKENDS):
+            raise ValueError(
+                f"transport {self.transport!r} starts one worker process "
+                f"per shard, and inner_backend {self.inner_backend!r} "
+                "opens the accelerator in each; the chip belongs to one "
+                "process, so use transport='local' for a device engine"
             )
 
     def replace(self, **changes: Any) -> "ClusterConfig":

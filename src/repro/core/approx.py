@@ -36,9 +36,8 @@ with ``_bsize``, and every hook degenerates to the parent's exact
 behavior — the engine is *bit-identical* to the SoA exact engine, which
 the oracle test in ``tests/test_tiered.py`` pins down.
 
-The batch support pass stays one kernel call: the sampled occupancy
-gather runs through ``repro.kernels.bucket_ops.bucket_core_stats`` on
-the device path, fed ``_ssize`` instead of ``_bsize``.
+The engine runs on the host: no backend places it on the device, so it
+has no device path of its own.
 """
 
 from __future__ import annotations
@@ -95,7 +94,7 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
     """Sampled-core approximate dynamic DBSCAN over the SoA layout."""
 
     def __init__(self, d: int, k: int, t: int, eps: float, seed: int = 0,
-                 use_device: bool = False, attach_orphans: bool = True,
+                 attach_orphans: bool = True,
                  lsh: Optional[GridLSH] = None, repair: str = "exact",
                  sample_rate: float = 1.0, approx_seed: int = 0):
         if not 0.0 < sample_rate <= 1.0:
@@ -103,7 +102,7 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
                 f"sample_rate must be in (0, 1], got {sample_rate}")
         self.sample_rate = float(sample_rate)
         self.approx_seed = int(approx_seed)
-        super().__init__(d, k, t, eps, seed=seed, use_device=use_device,
+        super().__init__(d, k, t, eps, seed=seed,
                          attach_orphans=attach_orphans, lsh=lsh,
                          repair=repair)
         # sampled-analogue support threshold (degenerates to k at 1.0,
@@ -187,35 +186,15 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
     def _batch_stats(self, slots: np.ndarray, flat: np.ndarray, ns: int,
                      smask: Optional[np.ndarray]):
         """Full occupancy drives membership; sampled occupancy drives
-        support.  Still one kernel call per batch on the device path —
-        ``bucket_core_stats`` just reads ``_ssize``."""
+        support."""
         rows_s = np.nonzero(smask)[0]
-        if self.use_device:
-            import jax.numpy as jnp
-
-            from repro.kernels import ops
-
-            impl = ("pallas_interpret" if self.use_device == "interpret"
-                    else None)
-            jslots = jnp.asarray(slots)
-            delta = np.asarray(ops.slot_counts(jslots, n_slots=ns, impl=impl))
-            self._bsize[:ns] += delta
-            sdelta = (delta if len(rows_s) == len(smask) else np.asarray(
-                ops.slot_counts(jnp.asarray(slots[rows_s]), n_slots=ns,
-                                impl=impl)))
-            self._ssize[:ns] += sdelta
-            supp, _core = ops.bucket_core_stats(
-                jslots, jnp.asarray(self._ssize[:ns]), k=self.core_k,
-                impl=impl)
-            supp = np.asarray(supp)
-        else:
-            delta = np.bincount(flat, minlength=ns).astype(np.int32)
-            self._bsize[:ns] += delta
-            sdelta = np.bincount(
-                slots[rows_s].ravel(), minlength=ns).astype(np.int32)
-            self._ssize[:ns] += sdelta
-            supp = np.add.reduce(
-                self._ssize[slots] >= self.core_k, axis=1, dtype=np.int32)
+        delta = np.bincount(flat, minlength=ns).astype(np.int32)
+        self._bsize[:ns] += delta
+        sdelta = np.bincount(
+            slots[rows_s].ravel(), minlength=ns).astype(np.int32)
+        self._ssize[:ns] += sdelta
+        supp = np.add.reduce(
+            self._ssize[slots] >= self.core_k, axis=1, dtype=np.int32)
         supp = np.where(smask, supp, 0).astype(np.int32)
         core_new = self._ssize[:ns]
         return core_new - sdelta, core_new, self._ssize[slots], supp

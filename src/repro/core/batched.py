@@ -30,7 +30,6 @@ class BatchedDynamicDBSCAN(DynamicDBSCAN):
         super().__init__(d, k, t, eps, seed=seed,
                          attach_orphans=attach_orphans, lsh=lsh, repair=repair)
         self.use_device = use_device
-        self._jax_fn = None
 
     # key space: kernel mixed keys (int32 pairs) instead of exact codes
     def _keys_of_batch(self, X: np.ndarray) -> List[list]:
@@ -54,7 +53,6 @@ class BatchedDynamicDBSCAN(DynamicDBSCAN):
             jnp.asarray(self.lsh.eta.astype(np.float32)),
             jnp.asarray(self.lsh.mixers),
             inv_cell=self.lsh.inv_cell,
-            impl="pallas_interpret" if self.use_device == "interpret" else None,
         )
 
     def add_point(self, x: np.ndarray, idx: Optional[int] = None) -> int:

@@ -5,10 +5,10 @@ eps-ball; the cluster graph connects every core point to everything in its
 eps-ball; clusters are connected components; non-core points with no core
 neighbour are noise.
 
-The neighbour counting / adjacency construction is the O(n² d) hot spot —
-on TPU it runs through the blocked Pallas kernel
-(``repro.kernels.pairwise_dist``); this host implementation uses the same
-blocking so memory stays O(n·B).
+The neighbour counting / adjacency construction is the O(n² d) hot spot.
+It runs on the host in numpy, in row blocks so memory stays O(n·B); the
+Pallas kernel ``repro.kernels.pairwise_dist`` is not on this path (and
+Mosaic refuses it for the TPU).
 """
 
 from __future__ import annotations

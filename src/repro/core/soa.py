@@ -16,7 +16,7 @@ per-bucket Python objects walked point-by-point, the engine keeps
 
 ``add_batch`` is one vectorised pass per batch — hash kernel → slot
 resolution → occupancy deltas → support gather → core transitions
-(``repro.kernels.bucket_ops`` on the device path) — with per-point Python
+(``repro.kernels.ops`` on the device path) — with per-point Python
 work only for the *events* of the sequential semantics: threshold
 crossings, orphan grabs, and border attachment.
 
@@ -55,6 +55,17 @@ from .hashing import GridLSH
 
 _KEY_W = 8  # mixed keys: 2 int32 words per (point, table)
 _EMPTY_MEMBERS: frozenset = frozenset()  # read-only _core_members default
+
+
+def _pad_rows(a: np.ndarray, fill) -> np.ndarray:
+    """Pad the leading axis to a power of two, at least one 256-row kernel
+    tile, so the device programs see O(log B) batch shapes rather than one
+    per batch size."""
+    n = len(a)
+    m = max(256, 1 << (n - 1).bit_length())
+    if m == n:
+        return a
+    return np.concatenate([a, np.full((m - n,) + a.shape[1:], fill, a.dtype)])
 
 
 class _LiveView:
@@ -207,20 +218,19 @@ class SoADynamicDBSCAN:
     def _hash_batch(self, X: np.ndarray) -> np.ndarray:
         """(B, d) -> (B, t, 2) int32 mixed keys (kernel key family)."""
         X32 = np.asarray(X, dtype=np.float32)
-        if self.use_device:
-            import jax.numpy as jnp
+        if not self.use_device:
+            return self.lsh.device_keys_batch(X32)
+        import jax.numpy as jnp
 
-            from repro.kernels import ops
+        from repro.kernels import ops
 
-            return np.asarray(ops.lsh_hash(
-                jnp.asarray(X32),
-                jnp.asarray(self.lsh.eta.astype(np.float32)),
-                jnp.asarray(self.lsh.mixers),
-                inv_cell=self.lsh.inv_cell,
-                impl=("pallas_interpret" if self.use_device == "interpret"
-                      else None),
-            ))
-        return self.lsh.device_keys_batch(X32)
+        keys = ops.lsh_hash(
+            jnp.asarray(_pad_rows(X32, 0.0)),
+            jnp.asarray(self.lsh.eta.astype(np.float32)),
+            jnp.asarray(self.lsh.mixers),
+            inv_cell=self.lsh.inv_cell,
+        )
+        return np.asarray(keys)[:len(X32)]
 
     # hot-path
     def _resolve_slots(self, keys32: np.ndarray) -> np.ndarray:
@@ -385,8 +395,8 @@ class SoADynamicDBSCAN:
     def _batch_stats(self, slots: np.ndarray, flat: np.ndarray, ns: int,
                      smask: Optional[np.ndarray]):
         """One array pass per insert batch — occupancy deltas + final
-        per-point support, via the kernel pass (``use_device``) or its
-        bit-exact numpy mirror.  Returns ``(core_old, core_new,
+        per-point support, via the device programs (``use_device``) or
+        their bit-exact numpy mirror.  Returns ``(core_old, core_new,
         occ_core, supp)``: the support-driving slot sizes before/after
         the batch, their per-(point, table) gather, and each batch
         point's final support."""
@@ -395,15 +405,17 @@ class SoADynamicDBSCAN:
 
             from repro.kernels import ops
 
-            impl = ("pallas_interpret" if self.use_device == "interpret"
-                    else None)
-            jslots = jnp.asarray(slots)
-            delta = np.asarray(ops.slot_counts(jslots, n_slots=ns, impl=impl))
+            # shapes come from the capacity-doubled arrays, so a stream
+            # compiles O(log n) programs: slots >= ns hold zero and no
+            # slot id reaches them, and the padded rows carry the
+            # out-of-range id ``cap``, which the scatter drops
+            cap = len(self._bsize)
+            jslots = jnp.asarray(_pad_rows(slots, cap))
+            delta = np.asarray(ops.slot_counts(jslots, n_slots=cap))[:ns]
             self._bsize[:ns] += delta
             supp, _core = ops.bucket_core_stats(
-                jslots, jnp.asarray(self._bsize[:ns]), k=self.core_k,
-                impl=impl)
-            supp = np.asarray(supp)
+                jslots, jnp.asarray(self._bsize), k=self.core_k)
+            supp = np.asarray(supp)[:len(slots)]
         else:
             delta = np.bincount(flat, minlength=ns).astype(np.int32)
             self._bsize[:ns] += delta

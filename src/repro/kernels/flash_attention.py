@@ -102,7 +102,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """GQA flash attention. q: (b, hq, sq, dh); k,v: (b, hkv, skv, dh)."""
     b, hq, sq, dh = q.shape

@@ -53,7 +53,7 @@ def lsh_hash(
     *,
     inv_cell: float,
     block_n: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """(n, d) f32 -> (n, t, 2) int32 bucket keys. See ref.lsh_hash."""
     n, d = x.shape

@@ -1,78 +1,45 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""The index's device programs, as the engines call them.
 
-Each op dispatches between the Mosaic TPU kernel and the pure-jnp reference
-(``ref.py``).  The dry-run lowers on a CPU backend where Mosaic kernels are
-unavailable, so ``impl='ref'`` is the default there; on real TPU hardware
-pass ``impl='pallas'`` (or set ``REPRO_KERNELS=pallas``).
+The platform decides where each one runs; no option or environment
+variable does:
+
+  * ``lsh_hash`` runs the compiled Pallas kernel (``lsh_hash.py``) when
+    JAX's default backend is a TPU, and the jitted jnp reference
+    (``ref.py``) on any other backend.
+  * ``slot_counts`` and ``bucket_core_stats`` are jitted jnp programs on
+    every backend.  Mosaic refuses a scatter-add and a 1-D gather inside a
+    TPU kernel, and XLA compiles both as fused device programs, so they
+    have no Pallas body.
 """
 
 from __future__ import annotations
 
-import os
+import functools
 
+import jax
 
-from . import bucket_ops as _bo
-from . import flash_attention as _fa
 from . import lsh_hash as _lh
-from . import pairwise_dist as _pd
 from . import ref as _ref
 
-
-def _impl(impl: str | None) -> str:
-    if impl is None:
-        impl = os.environ.get("REPRO_KERNELS", "ref")
-    if impl not in ("ref", "pallas", "pallas_interpret"):
-        raise ValueError(impl)
-    return impl
+_ref_lsh_hash = jax.jit(_ref.lsh_hash, static_argnames=("inv_cell",))
 
 
-def lsh_hash(x, eta, mixers, *, inv_cell: float, impl: str | None = None):
-    impl = _impl(impl)
-    if impl == "ref":
-        return _ref.lsh_hash(x, eta, mixers, inv_cell)
-    return _lh.lsh_hash(
-        x, eta, mixers, inv_cell=inv_cell, interpret=impl == "pallas_interpret"
-    )
+def lsh_hash(x, eta, mixers, *, inv_cell: float):
+    """(n, d) f32 -> (n, t, 2) int32 grid-LSH keys; see ``ref.lsh_hash``."""
+    if jax.default_backend() == "tpu":
+        return _lh.lsh_hash(x, eta, mixers, inv_cell=inv_cell)
+    return _ref_lsh_hash(x, eta, mixers, inv_cell=inv_cell)
 
 
-def bucket_core_stats(slots, sizes, *, k: int, impl: str | None = None):
-    impl = _impl(impl)
-    if impl == "ref":
-        return _ref.bucket_core_stats(slots, sizes, k)
-    return _bo.bucket_core_stats(
-        slots, sizes, k=k, interpret=impl == "pallas_interpret"
-    )
+@functools.partial(jax.jit, static_argnames=("k",))
+def bucket_core_stats(slots, sizes, *, k: int):
+    """(n, t) slots + (nb,) sizes -> (support, core); see
+    ``ref.bucket_core_stats``."""
+    return _ref.bucket_core_stats(slots, sizes, k)
 
 
-def slot_counts(slots, *, n_slots: int, impl: str | None = None):
-    impl = _impl(impl)
-    if impl == "ref":
-        return _ref.slot_counts(slots, n_slots)
-    return _bo.slot_counts(
-        slots, n_slots=n_slots, interpret=impl == "pallas_interpret"
-    )
-
-
-def eps_neighbor_counts(x, *, eps: float, impl: str | None = None):
-    impl = _impl(impl)
-    if impl == "ref":
-        return _ref.eps_neighbor_counts(x, eps)
-    return _pd.eps_neighbor_counts(
-        x, eps=eps, interpret=impl == "pallas_interpret"
-    )
-
-
-def attention(
-    q, k, v, *, causal=True, window=None, q_offset=0, scale=None,
-    impl: str | None = None, block_q: int = 128, block_k: int = 128,
-):
-    impl = _impl(impl)
-    if impl == "ref":
-        return _ref.attention(
-            q, k, v, causal=causal, window=window, q_offset=q_offset, scale=scale
-        )
-    return _fa.flash_attention(
-        q, k, v, causal=causal, window=window, q_offset=q_offset, scale=scale,
-        block_q=block_q, block_k=block_k,
-        interpret=impl == "pallas_interpret",
-    )
+@functools.partial(jax.jit, static_argnames=("n_slots",))
+def slot_counts(slots, *, n_slots: int):
+    """(n, t) slots -> (n_slots,) occupancy histogram; ids outside
+    ``[0, n_slots)`` are dropped.  See ``ref.slot_counts``."""
+    return _ref.slot_counts(slots, n_slots)

@@ -46,7 +46,7 @@ def eps_neighbor_counts(
     eps: float,
     block_m: int = 256,
     block_n: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """(n, d) -> (n,) int32 counts of points within eps (self included)."""
     n, d = x.shape

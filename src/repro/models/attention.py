@@ -1,9 +1,9 @@
 """GQA attention block: chunked (flash-style) jnp path + decode path.
 
 The jnp chunked path is the portable implementation the dry-run lowers
-(online softmax over q-chunks, O(chunk · kv) live memory); on TPU hardware
-the Pallas kernel (`repro.kernels.flash_attention`) slots in via
-``impl='pallas'``.  Decode attends one token against a (possibly
+(online softmax over q-chunks, O(chunk · kv) live memory); the Pallas
+kernel `repro.kernels.flash_attention` is its blocked counterpart, not
+called from here.  Decode attends one token against a (possibly
 sequence-sharded) KV cache; softmax/contraction over the sharded axis
 lowers to small all-reduces under GSPMD (DESIGN.md §4).
 """

@@ -19,16 +19,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace + old kwarg name
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, /, **kw):
-        kw["check_rep"] = kw.pop("check_vma", False)
-        return _shard_map(f, **kw)
 
 from . import layers as L
 

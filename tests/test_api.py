@@ -109,6 +109,18 @@ def test_config_validation(bad, named):
         ClusterConfig(**bad)
 
 
+@pytest.mark.parametrize("transport", ["process", "tcp"])
+def test_device_engine_refuses_worker_process_transport(transport):
+    """A wire transport spawns one process per shard; a device engine in
+    each would contend for the one chip, so the config is refused."""
+    base = dict(d=2, k=2, t=2, eps=0.5, backend="sharded", shards=2)
+    for inner in ("soa-device", "batched-device"):
+        with pytest.raises(ValueError, match="one process"):
+            ClusterConfig(**base, inner_backend=inner, transport=transport)
+    ClusterConfig(**base, inner_backend="soa-device", transport="local")
+    ClusterConfig(**base, inner_backend="soa", transport=transport)
+
+
 @pytest.mark.parametrize("backend", ALL_BACKENDS)
 def test_build_index_works_for_every_backend(backend):
     X, _ = blobs(n=200, d=3, n_clusters=3, cluster_std=0.15, seed=0)

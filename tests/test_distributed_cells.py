@@ -23,7 +23,8 @@ from repro.configs.base import ShapeConfig
 from repro.launch.cells import build_cell
 from repro.launch.hlo_analysis import analyze_compiled
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 out = {}
 
 def run(arch, shape_kind, execute=False):

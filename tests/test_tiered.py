@@ -63,12 +63,11 @@ def test_core_threshold_is_rescaled_to_the_sample():
     for k, rate, want in [(24, 0.1, 2), (24, 1.0, 24), (256, 0.1, 26),
                           (10, 0.05, 1), (8, 0.25, 2)]:
         eng = SampledCoreDBSCAN(d=4, k=k, t=4, eps=0.5, seed=0,
-                                sample_rate=rate, use_device=False)
+                                sample_rate=rate)
         assert eng.core_k == want
     # the exact engine keeps core_k == k (the degenerate rescaling)
     from repro.core.soa import SoADynamicDBSCAN
-    assert SoADynamicDBSCAN(d=4, k=24, t=4, eps=0.5, seed=0,
-                            use_device=False).core_k == 24
+    assert SoADynamicDBSCAN(d=4, k=24, t=4, eps=0.5, seed=0).core_k == 24
 
 
 # ---------------------------------------------------------------------- #
