@@ -6,15 +6,17 @@ path, including exceptions, or the dump shows a span that never ended
 protocol is exactly that guarantee, so the rule is simply that the
 protocol is used:
 
-  OBS001  a ``.span(...)`` / ``.timer(...)`` call in ``service/`` or
-          ``shard/`` that is not a ``with``-statement item — open-coded
-          ``__enter__``/manual timing can leak the span open on an
-          exception path
+  OBS001  a ``.span(...)`` / ``.timer(...)`` call in ``service/``,
+          ``shard/`` or ``core/soa.py`` that is not a ``with``-statement
+          item — open-coded ``__enter__``/manual timing can leak the span
+          open on an exception path
 
 Scoped to the protocol and coordinator modules (the ones whose spans
-cross the wire, where a leaked span corrupts a whole trace tree) — and to
-the two instrument factories by name, so unrelated ``.timer()`` APIs
-elsewhere never trip it.  Storing the context manager first
+cross the wire, where a leaked span corrupts a whole trace tree), to the
+vectorised engine (whose phase spans nest under the caller's and are the
+profiler's record of where its host time goes) — and to the two
+instrument factories by name, so unrelated ``.timer()`` APIs elsewhere
+never trip it.  Storing the context manager first
 (``cm = h.timer()`` ... ``with cm:``) also trips the rule by design:
 the repo's idiom is to open the scope where it is created.
 """
@@ -28,15 +30,16 @@ from .base import AnalysisPass, register_pass
 from .findings import Finding
 from .walker import Project, SourceFile
 
-_SCOPED_PREFIXES = ("service/", "shard/")
+_SCOPED_PREFIXES = ("service/", "shard/", "core/soa.py")
 _INSTRUMENT_FACTORIES = ("span", "timer")
 
 
 @register_pass
 class ObsDiscipline(AnalysisPass):
     name = "obs-discipline"
-    description = ("span/timer instruments in protocol modules are opened "
-                   "as context managers, never left to leak on exceptions")
+    description = ("span/timer instruments in protocol modules and the "
+                   "vectorised engine are opened as context managers, "
+                   "never left to leak on exceptions")
 
     def run(self, project: Project) -> List[Finding]:
         for sf in project.sources():

@@ -83,15 +83,19 @@ class _LiveView:
         return idx in self.a or idx in self.b
 
 
-def _sv_components(n_rows: int, a: np.ndarray, b: np.ndarray) -> np.ndarray:
+def _sv_components(n_rows: int, a: np.ndarray,
+                   b: np.ndarray) -> Tuple[np.ndarray, int]:
     """Shiloach–Vishkin style connectivity: parent pointer per row,
     hook-to-minimum + pointer-jumping until a fixpoint.  Returns the
     fully-compressed parent array (each row points at its component's
-    minimum row).  O((E + n) log n) pure array work."""
+    minimum row) and the number of hook rounds it took.  O((E + n) log n)
+    pure array work."""
     parent = np.arange(n_rows, dtype=np.int64)
+    rounds = 0
     if len(a) == 0:
-        return parent
+        return parent, rounds
     while True:
+        rounds += 1
         pa, pb = parent[a], parent[b]
         lo = np.minimum(pa, pb)
         hi = np.maximum(pa, pb)
@@ -103,7 +107,7 @@ def _sv_components(n_rows: int, a: np.ndarray, b: np.ndarray) -> np.ndarray:
                 break
             parent = pp
         if np.array_equal(parent[a], parent[b]):
-            return parent
+            return parent, rounds
 
 
 class SoADynamicDBSCAN:
@@ -224,13 +228,25 @@ class SoADynamicDBSCAN:
 
         from repro.kernels import ops
 
-        keys = ops.lsh_hash(
-            jnp.asarray(_pad_rows(X32, 0.0)),
-            jnp.asarray(self.lsh.eta.astype(np.float32)),
-            jnp.asarray(self.lsh.mixers),
-            inv_cell=self.lsh.inv_cell,
-        )
-        return np.asarray(keys)[:len(X32)]
+        Xp = _pad_rows(X32, 0.0)
+        eta = self.lsh.eta.astype(np.float32)
+        keys = ops.lsh_hash(jnp.asarray(Xp), jnp.asarray(eta),
+                            jnp.asarray(self.lsh.mixers),
+                            inv_cell=self.lsh.inv_cell)
+        with self.obs.tracer.span("soa.device.fetch"):
+            keys = np.asarray(keys)
+        if self.obs.enabled:
+            self._count_copies((Xp, eta, self.lsh.mixers), (keys,))
+        return keys[:len(X32)]
+
+    def _count_copies(self, to_device: Sequence[np.ndarray],
+                      to_host: Sequence[np.ndarray]) -> None:
+        """Count one device pass's host->device and device->host bytes
+        (``obs`` on only: callers test ``obs.enabled`` first)."""
+        self.obs.counter("soa.h2d_bytes").inc(
+            sum(a.nbytes for a in to_device))
+        self.obs.counter("soa.d2h_bytes").inc(
+            sum(a.nbytes for a in to_host))
 
     # hot-path
     def _resolve_slots(self, keys32: np.ndarray) -> np.ndarray:
@@ -272,89 +288,104 @@ class SoADynamicDBSCAN:
         B = X.shape[0]
         if B == 0:
             return []
-        k, t = self.core_k, self.t
+        k = self.core_k
+        tr = self.obs.tracer
+        with tr.span("soa.insert", n=B):
+            # -- claim handles (atomic: duplicates raise before any
+            #    mutation)
+            with tr.span("soa.insert.claim"):
+                staged: Dict[int, int] = {}
+                live = _LiveView(self._row, staged)
+                out: List[int] = []
+                for j in range(B):
+                    idx, self._next_idx = claim_index(
+                        live, self._next_idx,
+                        ids[j] if ids is not None else None)
+                    staged[idx] = j
+                    out.append(idx)
 
-        # -- claim handles (atomic: duplicates raise before any mutation)
-        staged: Dict[int, int] = {}
-        live = _LiveView(self._row, staged)
-        out: List[int] = []
-        for j in range(B):
-            idx, self._next_idx = claim_index(
-                live, self._next_idx, ids[j] if ids is not None else None)
-            staged[idx] = j
-            out.append(idx)
+            # -- one device pass: hash -> slots -> occupancy deltas.
+            #    smask marks the core-eligible batch points (None = all;
+            #    the sampled-core subclass narrows it), and the "core
+            #    sizes" the crossings run on are whatever _batch_stats
+            #    says drives support — bucket occupancy here, sampled
+            #    occupancy there.
+            with tr.span("soa.insert.hash"):
+                keys32 = self._hash_batch(X)
+            with tr.span("soa.insert.resolve_slots"):
+                slots = self._resolve_slots(keys32)
+            with tr.span("soa.insert.batch_stats"):
+                ns = self._n_slots
+                smask = self._elig_mask(out)
+                core_old, core_new, occ_core, supp_batch = self._batch_stats(
+                    slots, slots.ravel(), ns, smask)
 
-        # -- one device pass: hash -> slots -> occupancy deltas.  smask
-        #    marks the core-eligible batch points (None = all; the
-        #    sampled-core subclass narrows it), and the "core sizes" the
-        #    crossings run on are whatever _batch_stats says drives
-        #    support — bucket occupancy here, sampled occupancy there.
-        keys32 = self._hash_batch(X)
-        slots = self._resolve_slots(keys32)
-        ns = self._n_slots
-        flat = slots.ravel()
-        smask = self._elig_mask(out)
-        core_old, core_new, occ_core, supp_batch = self._batch_stats(
-            slots, flat, ns, smask)
+            with tr.span("soa.insert.crossings"):
+                # -- threshold crossings: which slots crossed k, and at
+                #    which step
+                crossing = np.nonzero((core_old < k) & (core_new >= k))[0]
+                cross_step = np.full(ns, B + 1, np.int64)  # B+1 = never
+                cross_step[core_new >= k] = -1  # already >= k...
+                if len(crossing):  # ...unless this batch crossed it
+                    cross_step[crossing] = self._cross_steps(
+                        crossing, core_old, slots, smask)
 
-        # -- threshold crossings: which slots crossed k, and at which step
-        crossing = np.nonzero((core_old < k) & (core_new >= k))[0]
-        cross_step = np.full(ns, B + 1, np.int64)      # B+1 = never crossed
-        cross_step[core_new >= k] = -1                 # already >= k...
-        if len(crossing):
-            cross_step[crossing] = self._cross_steps(
-                crossing, core_old, slots, smask)      # ...unless this batch
+                # -- existing members of crossing buckets gain support
+                #    (the sequential engine's "bucket crosses: every
+                #    member gains")
+                promoted_existing: Dict[int, int] = {}  # id -> core_time
+                for s in crossing:
+                    step = int(cross_step[s])
+                    for m in self._core_members(int(s)):
+                        if not self._core_candidate(m):
+                            continue
+                        r = self._row[m]
+                        self._support[r] += 1
+                        if self._support[r] == 1:
+                            promoted_existing[m] = step
+                        elif m in promoted_existing:
+                            # promotion time is the EARLIEST crossing
+                            # bucket's step, not the first in slot-id order
+                            promoted_existing[m] = min(
+                                promoted_existing[m], step)
 
-        # -- existing members of crossing buckets gain support (the
-        #    sequential engine's "bucket crosses: every member gains")
-        promoted_existing: Dict[int, int] = {}  # id -> core_time
-        for s in crossing:
-            step = int(cross_step[s])
-            for m in self._core_members(int(s)):
-                if not self._core_candidate(m):
-                    continue
-                r = self._row[m]
-                self._support[r] += 1
-                if self._support[r] == 1:
-                    promoted_existing[m] = step
-                elif m in promoted_existing:
-                    # promotion time is the EARLIEST crossing bucket's step,
-                    # not the first in slot-id order
-                    promoted_existing[m] = min(promoted_existing[m], step)
+            # -- membership: bulk per-slot set updates (grouped, C-speed)
+            with tr.span("soa.insert.members"):
+                self._add_members(slots, out)
 
-        # -- membership: bulk per-slot set updates (grouped, C-speed)
-        self._add_members(slots, out)
-        step_of = staged  # id -> batch step, for event-time filtering
+            with tr.span("soa.insert.commit"):
+                # -- commit batch rows
+                self._ensure_rows(self._top + B)
+                rows = np.empty(B, np.int64)
+                for j in range(B):
+                    r = self._free_rows.pop() if self._free_rows else self._top
+                    if r == self._top:
+                        self._top += 1
+                    rows[j] = r
+                    self._row[out[j]] = r
+                self._ids[rows] = out
+                self._pts[rows] = X
+                self._keys32[rows] = keys32
+                self._slots[rows] = slots
+                self._support[rows] = supp_batch
+                self._attach[rows] = -1
 
-        # -- commit batch rows
-        self._ensure_rows(self._top + B)
-        rows = np.empty(B, np.int64)
-        for j in range(B):
-            r = self._free_rows.pop() if self._free_rows else self._top
-            if r == self._top:
-                self._top += 1
-            rows[j] = r
-            self._row[out[j]] = r
-        self._ids[rows] = out
-        self._pts[rows] = X
-        self._keys32[rows] = keys32
-        self._slots[rows] = slots
-        self._support[rows] = supp_batch
-        self._attach[rows] = -1
+                # -- core_time per batch point: min over core buckets of
+                #    max(insert step, bucket cross step); non-core = B+1
+                steps = np.arange(B, dtype=np.int64)[:, None]
+                cand = np.where(occ_core >= k,
+                                np.maximum(cross_step[slots], steps), B + 1)
+                if smask is not None:
+                    cand = np.where(smask[:, None], cand, B + 1)
+                core_time = cand.min(axis=1)
 
-        # -- core_time per batch point: min over core buckets of
-        #    max(insert step, bucket cross step); non-core = B+1
-        steps = np.arange(B, dtype=np.int64)[:, None]
-        cand = np.where(occ_core >= k,
-                        np.maximum(cross_step[slots], steps), B + 1)
-        if smask is not None:
-            cand = np.where(smask[:, None], cand, B + 1)
-        core_time = cand.min(axis=1)
-
-        self._apply_insert_events(out, rows, slots, step_of, core_time,
-                                  promoted_existing, occ_core)
-        self._comp = None
-        self._compact_journal()
+            # staged maps id -> batch step, for event-time filtering
+            with tr.span("soa.insert.events"):
+                self._apply_insert_events(out, rows, slots, staged,
+                                          core_time, promoted_existing,
+                                          occ_core)
+            self._drop_epoch()
+            self._compact_journal()
         return out
 
     # ------------------------------------------------------------------ #
@@ -410,12 +441,20 @@ class SoADynamicDBSCAN:
             # slot id reaches them, and the padded rows carry the
             # out-of-range id ``cap``, which the scatter drops
             cap = len(self._bsize)
-            jslots = jnp.asarray(_pad_rows(slots, cap))
-            delta = np.asarray(ops.slot_counts(jslots, n_slots=cap))[:ns]
+            slots_p = _pad_rows(slots, cap)
+            jslots = jnp.asarray(slots_p)
+            counts = ops.slot_counts(jslots, n_slots=cap)
+            with self.obs.tracer.span("soa.device.fetch"):
+                counts = np.asarray(counts)
+            delta = counts[:ns]
             self._bsize[:ns] += delta
             supp, _core = ops.bucket_core_stats(
                 jslots, jnp.asarray(self._bsize), k=self.core_k)
-            supp = np.asarray(supp)[:len(slots)]
+            with self.obs.tracer.span("soa.device.fetch"):
+                supp = np.asarray(supp)
+            if self.obs.enabled:
+                self._count_copies((slots_p, self._bsize), (counts, supp))
+            supp = supp[:len(slots)]
         else:
             delta = np.bincount(flat, minlength=ns).astype(np.int32)
             self._bsize[:ns] += delta
@@ -672,20 +711,48 @@ class SoADynamicDBSCAN:
         """One array pass per batch: departure counts, threshold-crossing
         steps, and the occupancy decrement are computed for the whole
         batch up front (bincount + one stable argsort — the deletion
-        mirror of ``add_batch``'s insert pass); the per-point Python work
-        that remains is event-scale only (journal records, border
-        re-links, demotion cascades), replayed in deletion order so the
-        result is bit-identical to the sequential path."""
+        mirror of ``add_batch``'s insert pass).  The replay that follows
+        is per point: each deleted point writes its journal record and
+        leaves its ``t`` member sets, and the event-scale work (anchored
+        borders released, demotion cascades) rides along, in deletion
+        order so the result is bit-identical to the sequential path.
+        Border re-links run once, at the end of the batch."""
         check_unique_ids(ids)
         ids = [int(i) for i in ids]
-        if len(ids) <= 1 or any(i not in self._row for i in ids):
-            # tiny batches gain nothing from the array pass; a missing id
-            # keeps the sequential partial-prefix KeyError semantics
-            for i in ids:
-                self._delete_one(i)
-            self._comp = None
+        tr = self.obs.tracer
+        with tr.span("soa.expire", n=len(ids)):
+            if len(ids) <= 1 or any(i not in self._row for i in ids):
+                # tiny batches gain nothing from the array pass; a missing
+                # id keeps the sequential partial-prefix KeyError semantics
+                for i in ids:
+                    self._delete_one(i)
+                self._drop_epoch()
+                self._compact_journal()
+                return
+            with tr.span("soa.expire.departures"):
+                slots_d, cross_at, dep, new_sizes = self._departures(ids)
+            with tr.span("soa.expire.replay"):
+                pending = self._replay_deletes(ids, slots_d, cross_at)
+            with tr.span("soa.expire.relink"):
+                self._relink_pending(pending)
+                # emptied slots free once, at the end (their member sets
+                # emptied exactly when the final size reached zero)
+                for s in np.nonzero((dep > 0) & (new_sizes == 0))[0]:
+                    self._free_slot(int(s))
+            self._drop_epoch()
             self._compact_journal()
-            return
+
+    def _drop_epoch(self) -> None:
+        """Invalidate the connectivity epoch.  Its own span: releasing the
+        cached component array hands the rebuild's freed temporaries back
+        to the allocator, which can cost as much as a phase."""
+        with self.obs.tracer.span("soa.epoch.drop"):
+            self._comp = None
+
+    def _departures(self, ids: List[int]):
+        """The batch's array pass: each point's slots, the slots whose
+        support-driving size falls below the threshold keyed by the batch
+        step at which it does, and the occupancy decrement, applied."""
         k, t, D = self.core_k, self.t, len(ids)
         rows_d = np.fromiter((self._row[i] for i in ids), np.int64, D)
         slots_d = self._slots[rows_d]                  # (D, t)
@@ -719,17 +786,22 @@ class SoADynamicDBSCAN:
                 cross_at.setdefault(int(j), []).append(int(s))
 
         self._apply_occupancy_delta(dep, core_dep, ns)
+        return slots_d, cross_at, dep, new_sizes
 
-        # replay the sequential deletion events in batch order.  Border
-        # re-links are DEFERRED to one pass at the end: a disturbed
-        # border's sequential anchor is the min candidate, in the first
-        # table holding any, at its LAST re-link — and since candidate
-        # sets only shrink during a delete batch (no inserts, demotions
-        # only) while the chosen anchor by definition survives, that
-        # equals the min live core at batch end.  The sequential path's
-        # intermediate hops (re-anchor to a core deleted later in the
-        # batch, cascading more re-links) net out of the compacted
-        # journal, so state and delta feed are both bit-identical.
+    def _replay_deletes(self, ids: List[int], slots_d: np.ndarray,
+                        cross_at: Dict[int, List[int]]) -> Set[int]:
+        """Replay the sequential deletion events in batch order; returns
+        the disturbed borders, whose re-links are DEFERRED to one pass at
+        the end: a disturbed border's sequential anchor is the min
+        candidate, in the first table holding any, at its LAST re-link —
+        and since candidate sets only shrink during a delete batch (no
+        inserts, demotions only) while the chosen anchor by definition
+        survives, that equals the min live core at batch end.  The
+        sequential path's intermediate hops (re-anchor to a core deleted
+        later in the batch, cascading more re-links) net out of the
+        compacted journal, so state and delta feed are both
+        bit-identical."""
+        t = self.t
         pending: Set[int] = set()
         for j, idx in enumerate(ids):
             row = self._row[idx]
@@ -767,10 +839,13 @@ class SoADynamicDBSCAN:
             self._attach[row] = -1
             self._free_rows.append(row)
             del self._row[idx]
+        return pending
 
-        # end-of-batch re-link: min live core per slot, computed once per
-        # slot and shared across every disturbed border (the sequential
-        # cascade touches the same blob buckets over and over)
+    def _relink_pending(self, pending: Set[int]) -> None:
+        """End-of-batch re-link: min live core per slot, computed once per
+        slot and shared across every disturbed border (the sequential
+        cascade touches the same blob buckets over and over)."""
+        t = self.t
         slot_best: Dict[int, int] = {}
         for y in pending:
             ry = self._row.get(y)
@@ -789,13 +864,6 @@ class SoADynamicDBSCAN:
                     self.anchored.setdefault(c, set()).add(y)
                     self._record(y, None, c)
                     break
-
-        # emptied slots free once, at the end (their member sets emptied
-        # exactly when the final size reached zero)
-        for s in np.nonzero((dep > 0) & (new_sizes == 0))[0]:
-            self._free_slot(int(s))
-        self._comp = None
-        self._compact_journal()
 
     def _apply_occupancy_delta(self, dep: np.ndarray, core_dep: np.ndarray,
                                ns: int) -> None:
@@ -899,25 +967,31 @@ class SoADynamicDBSCAN:
     def _ensure_comp(self) -> np.ndarray:
         if self._comp is not None:
             return self._comp
-        rows = np.fromiter(self._row.values(), np.int64, len(self._row))
-        core_rows = rows[self._support[rows] > 0]
-        a = b = np.zeros(0, np.int64)
-        if len(core_rows):
-            S = self._slots[core_rows]                    # (m, t)
-            flat = S.ravel()
-            rep = np.repeat(core_rows, self.t)
-            order = np.argsort(flat, kind="stable")
-            sf, rf = flat[order], rep[order]
-            same = sf[1:] == sf[:-1]
-            a, b = rf[:-1][same], rf[1:][same]
-        parent = _sv_components(self._top, a, b)
-        comp = np.full(self._cap, -1, np.int64)
-        if len(core_rows):
-            comp[core_rows] = self._ids[parent[core_rows]]
+        tr = self.obs.tracer
+        with tr.span("soa.rebuild") as sp:
+            with tr.span("soa.rebuild.edges"):
+                rows = np.fromiter(self._row.values(), np.int64,
+                                   len(self._row))
+                core_rows = rows[self._support[rows] > 0]
+                a = b = np.zeros(0, np.int64)
+                if len(core_rows):
+                    S = self._slots[core_rows]                    # (m, t)
+                    flat = S.ravel()
+                    rep = np.repeat(core_rows, self.t)
+                    order = np.argsort(flat, kind="stable")
+                    sf, rf = flat[order], rep[order]
+                    same = sf[1:] == sf[:-1]
+                    a, b = rf[:-1][same], rf[1:][same]
+            with tr.span("soa.rebuild.sv"):
+                parent, rounds = _sv_components(self._top, a, b)
+            comp = np.full(self._cap, -1, np.int64)
+            if len(core_rows):
+                comp[core_rows] = self._ids[parent[core_rows]]
+            if self.obs.enabled:
+                sp.attrs.update(edges=len(a), rounds=rounds)
+                self.obs.counter("soa.sv_rounds").inc(rounds)
         self._comp = comp
         self.n_epoch_rebuilds += 1
-        if self.obs.enabled:
-            self.obs.histogram("engine.cc_edges").observe(len(a))
         return comp
 
     def get_cluster(self, idx: int):
@@ -978,13 +1052,15 @@ class SoADynamicDBSCAN:
     def _compact_journal(self) -> None:
         if not self._journal:
             return
-        merged: Dict[int, List[Optional[int]]] = {}
-        for idx, old, new in self._journal:
-            if idx in merged:
-                merged[idx][1] = new
-            else:
-                merged[idx] = [old, new]
-        self._journal = [(i, o, n) for i, (o, n) in merged.items() if o != n]
+        with self.obs.tracer.span("soa.journal.compact"):
+            merged: Dict[int, List[Optional[int]]] = {}
+            for idx, old, new in self._journal:
+                if idx in merged:
+                    merged[idx][1] = new
+                else:
+                    merged[idx] = [old, new]
+            self._journal = [(i, o, n) for i, (o, n) in merged.items()
+                             if o != n]
 
     def drain_deltas(self) -> List[Tuple[int, Optional[int], Optional[int]]]:
         if self._journal is None:

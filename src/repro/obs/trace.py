@@ -20,6 +20,12 @@ summaries that the client :meth:`Tracer.ingest`\\ s.
 Buffers are bounded: past ``capacity`` finished spans are counted in
 ``dropped`` instead of stored, so tracing a long run degrades to a
 truncated dump, never to unbounded memory.
+
+An enabled span is also a ``jax.profiler.TraceAnnotation`` of the same
+name while it is open, so under a profiler session (``jax.profiler.trace``)
+it lands on the profiler's host plane, on the device planes' clock.  This
+module never imports JAX: a process that has not loaded it cannot be
+profiled, and its spans skip the annotation.
 """
 
 from __future__ import annotations
@@ -28,6 +34,7 @@ import contextlib
 import contextvars
 import itertools
 import os
+import sys
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
@@ -104,7 +111,8 @@ class Tracer:
     def span(self, name: str, **attrs: Any) -> Iterator[Span]:
         """Record a span around the ``with`` block.  A span started while
         another is current becomes its child; otherwise it roots a new
-        trace."""
+        trace.  Under a profiler session it is also a profiler host event
+        named ``name`` (``name#attr=value,...#`` with ``attrs``)."""
         parent = _CURRENT.get()
         sid = _new_id()
         if parent is None:
@@ -112,11 +120,15 @@ class Tracer:
         else:
             sp = Span(name, parent.trace_id, sid, parent.span_id,
                       self.proc, attrs)
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        note = (_NULL_CM if prof is None
+                else prof.TraceAnnotation(name, **attrs))
         sp.ts_us = time.time() * 1e6
         sp._t0 = time.perf_counter()
         token = _CURRENT.set(sp)
         try:
-            yield sp
+            with note:
+                yield sp
         finally:
             _CURRENT.reset(token)
             sp.dur_us = (time.perf_counter() - sp._t0) * 1e6

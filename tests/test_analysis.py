@@ -585,6 +585,21 @@ class TestObsDiscipline:
         """})
         assert ObsDiscipline().run(project) == []
 
+    def test_vectorised_engine_is_in_scope(self, tmp_path):
+        project = make_project(tmp_path, {
+            "core/soa.py": """\
+                class Engine:
+                    def rebuild(self):
+                        sp = self.obs.tracer.span("soa.rebuild")
+                        with self.obs.tracer.span("soa.rebuild.sv"):
+                            return sp
+            """,
+            "core/dynamic_dbscan.py": """\
+                def probe(h):
+                    return h.timer()
+            """})
+        assert rules(ObsDiscipline().run(project)) == ["OBS001"]
+
     def test_scope_is_service_and_shard_only(self, tmp_path):
         # an unrelated .timer() API outside the protocol modules is fine
         project = make_project(tmp_path, {"serving/loop.py": """\

@@ -191,6 +191,45 @@ class TestTrace:
                 pass
         assert len(tr.export()) == 2 and tr.dropped == 3
 
+    def test_enabled_span_is_a_profiler_host_event(self, tmp_path):
+        # under a profiler session a span is also a host event on the
+        # profiler's clock; the null tracer's spans record nothing there
+        import jax
+        from jax.profiler import ProfileData
+
+        tr = Tracer()
+        with jax.profiler.trace(str(tmp_path)):
+            with tr.span("obs.test.outer", n=2):
+                with tr.span("obs.test.inner"):
+                    pass
+                with NULL_OBS.tracer.span("obs.test.null"):
+                    pass
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        pd = ProfileData.from_file(str(path))
+        names = {ev.name.split("#")[0] for plane in pd.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert {"obs.test.outer", "obs.test.inner"} <= names
+        assert "obs.test.null" not in names
+        # the in-memory buffer is unchanged by the annotation
+        assert [s["name"] for s in tr.export()] == ["obs.test.inner",
+                                                    "obs.test.outer"]
+
+    def test_import_and_spans_leave_jax_unimported(self):
+        # shard workers import repro.obs; neither the import nor a span,
+        # enabled or null, may pull JAX into a process that lacks it
+        code = ("import sys\n"
+                "import repro.obs as o\n"
+                "with o.Tracer().span('a', n=1):\n"
+                "    pass\n"
+                "with o.NULL_TRACER.span('b'):\n"
+                "    pass\n"
+                "print('jax' in sys.modules)\n")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == "False"
+
 
 # ---------------------------------------------------------------------- #
 # exporters
