@@ -40,7 +40,11 @@ query rebuilds): chain edges are consecutive core rows per slot, and the
 component labelling is a vectorised Shiloach–Vishkin hook+shortcut pass
 (the data-parallel connectivity of Wang et al.'s parallel DBSCAN) — no
 scipy dependency, O(E log n) array work, amortised across every label
-query in the epoch.
+query in the epoch.  With ``use_device`` the epoch is one device program
+instead (``ops.core_components``): the same hook+shortcut over the much
+smaller bucket graph, where each core row joins its ``t`` slots — two
+cores share a component exactly when a path of shared buckets joins
+them, so the components, and the least-row handles, are the same.
 """
 
 from __future__ import annotations
@@ -156,6 +160,9 @@ class SoADynamicDBSCAN:
             List[Tuple[int, Optional[int], Optional[int]]]] = None
         # epoch cache: row -> component handle for core rows (None = dirty)
         self._comp: Optional[np.ndarray] = None
+        # the device epoch's renumbered slot matrix, kept across epochs so
+        # that each rebuild writes into pages already mapped
+        self._ranked: Optional[np.ndarray] = None
 
         # instrumentation (adapter stats())
         self.n_epoch_rebuilds = 0
@@ -968,31 +975,83 @@ class SoADynamicDBSCAN:
         if self._comp is not None:
             return self._comp
         tr = self.obs.tracer
-        with tr.span("soa.rebuild") as sp:
-            with tr.span("soa.rebuild.edges"):
-                rows = np.fromiter(self._row.values(), np.int64,
-                                   len(self._row))
-                core_rows = rows[self._support[rows] > 0]
-                a = b = np.zeros(0, np.int64)
-                if len(core_rows):
-                    S = self._slots[core_rows]                    # (m, t)
-                    flat = S.ravel()
-                    rep = np.repeat(core_rows, self.t)
-                    order = np.argsort(flat, kind="stable")
-                    sf, rf = flat[order], rep[order]
-                    same = sf[1:] == sf[:-1]
-                    a, b = rf[:-1][same], rf[1:][same]
-            with tr.span("soa.rebuild.sv"):
-                parent, rounds = _sv_components(self._top, a, b)
-            comp = np.full(self._cap, -1, np.int64)
-            if len(core_rows):
-                comp[core_rows] = self._ids[parent[core_rows]]
+        with tr.span("soa.rebuild", on_device=self.use_device) as sp:
+            if self.use_device:
+                comp, edges, rounds = self._device_comp()
+            else:
+                comp, edges, rounds = self._host_comp()
             if self.obs.enabled:
-                sp.attrs.update(edges=len(a), rounds=rounds)
+                sp.attrs.update(edges=edges, rounds=rounds)
                 self.obs.counter("soa.sv_rounds").inc(rounds)
+                if self.use_device:
+                    self.obs.counter("soa.rebuild.device").inc()
         self._comp = comp
         self.n_epoch_rebuilds += 1
         return comp
+
+    def _host_comp(self) -> Tuple[np.ndarray, int, int]:
+        """The epoch on the host: chain edges between consecutive core
+        rows of each slot, then ``_sv_components`` over the rows.  Returns
+        the component handles by row, the edge count and the rounds."""
+        tr = self.obs.tracer
+        with tr.span("soa.rebuild.edges"):
+            rows = np.fromiter(self._row.values(), np.int64, len(self._row))
+            core_rows = rows[self._support[rows] > 0]
+            a = b = np.zeros(0, np.int64)
+            if len(core_rows):
+                S = self._slots[core_rows]                    # (m, t)
+                flat = S.ravel()
+                rep = np.repeat(core_rows, self.t)
+                order = np.argsort(flat, kind="stable")
+                sf, rf = flat[order], rep[order]
+                same = sf[1:] == sf[:-1]
+                a, b = rf[:-1][same], rf[1:][same]
+        with tr.span("soa.rebuild.sv"):
+            parent, rounds = _sv_components(self._top, a, b)
+        comp = np.full(self._cap, -1, np.int64)
+        if len(core_rows):
+            comp[core_rows] = self._ids[parent[core_rows]]
+        return comp, len(a), rounds
+
+    def _device_comp(self) -> Tuple[np.ndarray, int, int]:
+        """The epoch as one device program over the bucket graph
+        (``ops.core_components``): the slot matrix and the core mask go
+        in, each slot's least core row of its component comes back, and a
+        core row's handle is the id of that row for its first slot, the
+        same value the host path gives.  Returns the handles by row, the
+        (core row, slot) incidences and the rounds."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.kernels import ops
+
+        tr = self.obs.tracer
+        with tr.span("soa.rebuild.edges"):
+            # freed rows reset _ids and _support, so this is live & core
+            core = (self._ids >= 0) & (self._support > 0)
+            # slot ids renumbered by falling occupancy: each cluster's
+            # fullest bucket takes its least id, so the program's first
+            # round, which hooks every row to its least slot, settles
+            # nearly every row
+            ns = len(self._bsize)
+            rank = np.empty(ns, np.int32)
+            rank[np.argsort(-self._bsize, kind="stable")] = np.arange(
+                ns, dtype=np.int32)
+            if self._ranked is None or len(self._ranked) != self._cap:
+                self._ranked = np.empty_like(self._slots)
+            slots = np.take(rank, self._slots, out=self._ranked)
+            jslots, jcore = jnp.asarray(slots.reshape(-1)), jnp.asarray(core)
+        with tr.span("soa.rebuild.sv"):
+            least, rounds = ops.core_components(jslots, jcore, n_slots=ns)
+            with tr.span("soa.device.fetch"):
+                least, rounds = jax.device_get((least, rounds))
+        comp = np.full(self._cap, -1, np.int64)
+        comp[core] = self._ids[least[slots[core, 0]]]
+        edges = 0
+        if self.obs.enabled:
+            self._count_copies((slots, core), (least, rounds))
+            edges = int(np.count_nonzero(core)) * self.t
+        return comp, edges, int(rounds)
 
     def get_cluster(self, idx: int):
         """Component handle: the id of the component's representative core

@@ -6,10 +6,10 @@ variable does:
   * ``lsh_hash`` runs the compiled Pallas kernel (``lsh_hash.py``) when
     JAX's default backend is a TPU, and the jitted jnp reference
     (``ref.py``) on any other backend.
-  * ``slot_counts`` and ``bucket_core_stats`` are jitted jnp programs on
-    every backend.  Mosaic refuses a scatter-add and a 1-D gather inside a
-    TPU kernel, and XLA compiles both as fused device programs, so they
-    have no Pallas body.
+  * ``slot_counts``, ``bucket_core_stats`` and ``core_components`` are
+    jitted jnp programs on every backend.  Mosaic refuses a scatter and a
+    1-D gather inside a TPU kernel, and XLA compiles them as fused device
+    programs, so they have no Pallas body.
 """
 
 from __future__ import annotations
@@ -43,3 +43,13 @@ def slot_counts(slots, *, n_slots: int):
     """(n, t) slots -> (n_slots,) occupancy histogram; ids outside
     ``[0, n_slots)`` are dropped.  See ``ref.slot_counts``."""
     return _ref.slot_counts(slots, n_slots)
+
+
+@functools.partial(jax.jit, static_argnames=("n_slots",))
+def core_components(slots, core, *, n_slots: int):
+    """(n * t,) flat slots + (n,) core mask -> (least, rounds): for each
+    of the ``n_slots`` slots the least core row of its component over the
+    bucket graph.  The slots come flat because a flat array crosses to the
+    chip as it is, where an (n, t) one is relaid on the host into the
+    chip's tiled layout first.  See ``ref.core_components``."""
+    return _ref.core_components(slots, core, n_slots)

@@ -70,3 +70,19 @@ def test_occupancy_and_support_programs_compile(one_chip, cap):
     stats = ops.bucket_core_stats.lower(
         slots, _shape(one_chip, (cap,), jnp.int32), k=10).compile()
     assert [o.shape for o in stats.out_info] == [(1024,), (1024,)]
+
+
+# the connectivity epoch over the paper's 200k-point window (row capacity
+# 2^18): the blobs' dense buckets fill a slot capacity of 2^14, sparse
+# buckets can take it to 2^21
+@pytest.mark.parametrize("n_slots", [2**14, 2**21])
+def test_core_components_program_compiles(one_chip, n_slots):
+    rows = 2**18
+    compiled = ops.core_components.lower(
+        _shape(one_chip, (rows * T,), jnp.int32),
+        _shape(one_chip, (rows,), jnp.bool_),
+        n_slots=n_slots,
+    ).compile()
+    least, rounds = compiled.out_info
+    assert least.shape == (n_slots,) and least.dtype == jnp.int32
+    assert rounds.shape == () and rounds.dtype == jnp.int32

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.api import (
+    NOISE,
     ClusterConfig,
     build_index,
     restore_index,
@@ -61,6 +62,53 @@ def test_slot_counts_matches_bincount(n, t, nb):
     padded = np.concatenate([slots, np.full((5, t), 2 * nb, np.int32)])
     got = np.asarray(ops.slot_counts(jnp.asarray(padded), n_slots=2 * nb))
     assert np.array_equal(got[:nb], want) and not got[nb:].any()
+
+
+def _host_least_rows(slots, core):
+    """Least core row of each core row's component, -1 elsewhere: the host
+    SV over chain edges, as the soa engine computes its epoch."""
+    from repro.core.soa import _sv_components
+
+    n, t = slots.shape
+    rows = np.nonzero(core)[0]
+    flat = slots[rows].ravel()
+    order = np.argsort(flat, kind="stable")
+    sf, rf = flat[order], np.repeat(rows, t)[order]
+    same = sf[1:] == sf[:-1]
+    parent, _ = _sv_components(n, rf[:-1][same], rf[1:][same])
+    return np.where(core, parent, -1)
+
+
+@pytest.mark.parametrize("block", [1, 7, 64, 4096])
+@pytest.mark.parametrize("shape", ["random", "chain"])
+def test_core_components_matches_host_sv(shape, block):
+    """The device epoch program against the host SV on graphs of random
+    and path shape, with blocks small enough that the first round, the
+    check and the later rounds each cross several blocks, rows past the
+    last core row, and packed leftovers."""
+    import jax
+
+    from repro.kernels import ref
+
+    rng = np.random.default_rng(block)
+    program = jax.jit(ref.core_components, static_argnums=(2, 3))
+    for t in range(1, 5):
+        n = int(rng.integers(2, 300))
+        if shape == "random":
+            # slot ids distinct across tables, as the directory gives them
+            slots = rng.integers(0, 40, (n, t)) * t + np.arange(t)
+        else:
+            # row j joins slots j and j+1 of a scrambled path
+            path = rng.permutation(n + 1)
+            slots = np.stack([path[:-1], path[1:]] * t, axis=1)[:, :t]
+        slots = slots.astype(np.int32)
+        core = rng.random(n) < 0.8
+        core[int(rng.integers(n)):] &= t % 2 == 0  # a dead tail
+        n_slots = int(slots.max()) + 1 + t
+        least, rounds = program(slots.reshape(-1), core, n_slots, block)
+        got = np.where(core, np.asarray(least)[slots[:, 0]], -1)
+        assert np.array_equal(got, _host_least_rows(slots, core))
+        assert (int(rounds) > 0) == bool(core.any())
 
 
 # ---------------------------------------------------------------------- #
@@ -179,3 +227,91 @@ def test_sharded_soa_snapshot_roundtrip():
     rest = restore_index(sh.snapshot())
     assert rest.labels() == sh.labels()
     assert rest.ids() == sh.ids()
+
+
+# ---------------------------------------------------------------------- #
+# the connectivity epoch on the device (soa-device) vs the host SV (soa)
+# ---------------------------------------------------------------------- #
+EPS, K, T = 0.5, 3, 4
+
+
+def _chain(rng, n_clumps, x0=0.0):
+    """K copies of a point every EPS along a line: consecutive clumps
+    share a bucket in some tables, clumps two apart in none, so the core
+    set is one path of buckets.  Clumps arrive in random order, so slot
+    ids are scrambled along the path."""
+    pos = rng.permutation(n_clumps)
+    X = np.zeros((n_clumps * K, 2))
+    X[:, 0] = x0 + np.repeat(pos * EPS, K)
+    return X, np.repeat(pos, K)
+
+
+def _epoch_script(case, rng):
+    """(op, arg) steps; every mutation is followed by an epoch check."""
+    if case == "split":
+        X, pos = _chain(rng, 24)
+        ext, _ = _chain(rng, 6, x0=40.0)
+        # the middle clump goes, then a point of each remaining clump
+        return [("insert", X), ("insert", ext),
+                ("delete", np.nonzero(pos == 12)[0]),
+                ("delete", np.nonzero(np.diff(pos, prepend=-1))[0][::3])]
+    if case == "chain":
+        X, _ = _chain(rng, 64)
+        return [("insert", X[:K * 40]), ("insert", X[K * 40:])]
+    if case == "no_core":
+        far = np.zeros((20, 2))
+        far[:, 1] = np.arange(20) * 10 * EPS  # one point a bucket
+        X, _ = _chain(rng, 3, x0=-50.0)
+        return [("insert", far), ("insert", X),
+                ("delete", np.arange(20, 20 + len(X)))]
+    if case == "doubling":
+        # the first epoch at row capacity 256 and slot capacity 256, the
+        # second after both have doubled
+        X, _ = _chain(rng, 200)
+        return [("insert", X[:60]), ("insert", X[60:])]
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("orphans", [True, False])
+@pytest.mark.parametrize("case", ["split", "chain", "no_core", "doubling"])
+def test_device_epoch_matches_host_epoch(case, orphans):
+    """soa-device computes the epoch on the device over the bucket graph,
+    soa with the host SV over chain edges: the same handles, labels() and
+    drained deltas after every epoch."""
+    rng = np.random.default_rng(7)
+    cfg = ClusterConfig(d=2, k=K, t=T, eps=EPS, seed=1,
+                        attach_orphans=orphans, obs=True)
+    host = build_index(cfg.replace(backend="soa"))
+    dev = build_index(cfg.replace(backend="soa-device"))
+    host.drain_deltas(), dev.drain_deltas()
+    caps = []
+    ids: list = []
+    for op, arg in _epoch_script(case, rng):
+        if op == "insert":
+            got = host.insert_batch(arg)
+            assert dev.insert_batch(arg) == got
+            ids += got
+        else:
+            dels = [ids[int(j)] for j in arg]
+            host.delete_batch(dels)
+            dev.delete_batch(dels)
+            ids = [i for i in ids if i not in set(dels)]
+        assert [dev.label(i) for i in ids] == [host.label(i) for i in ids]
+        assert dev.labels() == host.labels()
+        assert dev.drain_deltas() == host.drain_deltas()
+        caps.append((dev.engine._cap, len(dev.engine._bsize)))
+    assert dev.engine.n_epoch_rebuilds == len(caps)
+    rounds = [s["args"]["rounds"] for s in dev.obs.tracer.export()
+              if s["name"] == "soa.rebuild"]
+    assert all(s["args"]["on_device"] for s in dev.obs.tracer.export()
+               if s["name"] == "soa.rebuild")
+    labels = dev.labels()
+    if case == "split":
+        # two chains, then the first cut in two
+        assert len(set(labels.values()) - {NOISE}) == 3
+    elif case == "chain":
+        assert len(set(labels.values())) == 1 and max(rounds) >= 4
+    elif case == "no_core":
+        assert rounds[-1] == 0 and set(labels.values()) == {NOISE}
+    else:
+        assert caps[0] == (256, 256) and caps[1][0] > 256 < caps[1][1]

@@ -44,7 +44,8 @@ EXPIRE = {("soa.expire", None), ("soa.journal.compact", "soa.expire"),
     (f"soa.expire.{p}", "soa.expire")
     for p in ("departures", "replay", "relink")}
 REBUILD = {("soa.rebuild", None), ("soa.rebuild.edges", "soa.rebuild"),
-           ("soa.rebuild.sv", "soa.rebuild")}
+           ("soa.rebuild.sv", "soa.rebuild"),
+           ("soa.device.fetch", "soa.rebuild.sv")}
 
 
 def test_insert_expire_label_span_tree():
@@ -96,12 +97,22 @@ def test_copy_bytes_match_the_padded_shapes():
     m = ix.obs.snapshot()["metrics"]
     assert m["soa.h2d_bytes"]["value"] == h2d
     assert m["soa.d2h_bytes"]["value"] == d2h
-    # expiry and the rebuild copy nothing
+    # expiry copies nothing; the rebuild ships the whole slot matrix and
+    # the core mask at the row capacity, and fetches one least core row
+    # per slot and the round count
     ix.delete_batch(list(ix.ids())[:40])
-    ix.labels()
     m2 = ix.obs.snapshot()["metrics"]
     assert m2["soa.h2d_bytes"] == m["soa.h2d_bytes"]
     assert m2["soa.d2h_bytes"] == m["soa.d2h_bytes"]
+    ix.labels()
+    assert ix.engine._cap == rows
+    m3 = ix.obs.snapshot()["metrics"]
+    assert m3["soa.h2d_bytes"]["value"] == h2d + (
+        rows * T * 4              # slots, int32
+        + rows)                   # core mask, bool
+    assert m3["soa.d2h_bytes"]["value"] == d2h + (
+        cap * 4                   # least core row per slot, int32
+        + 4)                      # rounds, int32
 
 
 def test_host_engine_counts_no_copies():
@@ -124,9 +135,30 @@ def test_rebuild_counts_sv_rounds_and_edges():
     rounds = [s["args"]["rounds"] for s in rebuilds]
     assert all(r >= 1 for r in rounds)
     assert all(s["args"]["edges"] > 0 for s in rebuilds)
+    assert all(s["args"]["on_device"] for s in rebuilds)
     m = ix.obs.snapshot()["metrics"]
     assert m["soa.sv_rounds"]["value"] == sum(rounds)
-    assert set(m) == {"soa.h2d_bytes", "soa.d2h_bytes", "soa.sv_rounds"}
+    # every epoch of soa-device is computed on the device
+    assert m["soa.rebuild.device"]["value"] == ix.engine.n_epoch_rebuilds
+    assert set(m) == {"soa.h2d_bytes", "soa.d2h_bytes", "soa.sv_rounds",
+                      "soa.rebuild.device"}
+
+
+def test_host_engine_rebuilds_on_the_host():
+    ix = build_index(cfg(backend="soa"))
+    ids = ix.insert_batch(points())
+    ix.labels()
+    ix.delete_batch(ids[:30])
+    ix.labels()
+    rebuilds = [s for s in ix.obs.tracer.export()
+                if s["name"] == "soa.rebuild"]
+    assert len(rebuilds) == ix.engine.n_epoch_rebuilds == 2
+    assert not any(s["args"]["on_device"] for s in rebuilds)
+    assert all(s["args"]["edges"] > 0 for s in rebuilds)
+    m = ix.obs.snapshot()["metrics"]
+    assert m["soa.sv_rounds"]["value"] == sum(
+        s["args"]["rounds"] for s in rebuilds)
+    assert set(m) == {"soa.sv_rounds"}
 
 
 @pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
