@@ -14,6 +14,12 @@ mix or one per-layer metric sits in a file of its own, found by name:
   * ``bench/metrics/<metric>.py``: ``read(run) -> float | None``, one
     per-layer metric from the run's records and trace.
 
+A traced run also turns the engine's observability on
+(``ClusterConfig.obs``): ``core/soa.py`` then opens a ``soa.*`` span per
+phase, which lands in the profiler's trace, and counts its copies.  An
+untraced run keeps it off, so the end-to-end metrics time the engine as a
+user's process runs it.
+
 So a later change adds a cell, a configuration, a mix or a metric by
 adding files and entries, and edits none.  The end-to-end metrics are
 computed here, from the host clock over the whole window.
@@ -144,6 +150,11 @@ class Run:
     label_after_mutation: List[float]  # first label() after a mutation
     trace: Optional[dict]           # trace_reduce.reduce(), traced runs
     peaks: Optional[dict]           # peaks.json row of the device
+    steps: int = 0                  # whole steps the window ran
+    # the engine's counters over the window and the spans its tracer
+    # dropped there (traced runs; readers of spans trust none if any were)
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
+    dropped: int = 0
 
 
 class _CompileCounter:
@@ -262,6 +273,11 @@ class _Driver:
                 raise ValueError(f"unknown op {name!r} in mix")
 
 
+def _counters(obs) -> Dict[str, int]:
+    return {name: int(s["value"]) for name, s in obs.metrics.snapshot().items()
+            if s["type"] == "counter"}
+
+
 def _p95(xs: List[float]) -> float:
     return float(np.percentile(np.asarray(xs, np.float64), 95))
 
@@ -290,7 +306,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     lsh_seed = int(conf["lsh_seed"])
     ccfg = ClusterConfig(d=conf["d"], k=conf["k"], t=conf["t"],
                          eps=conf["eps"], seed=lsh_seed,
-                         backend=conf["backend"])
+                         backend=conf["backend"], obs=trace)
     batch = int(cell.mix["batch"] or conf["batch"])
     window = int(conf["live_window"])
     index = (make_index or build_index)(ccfg)
@@ -328,6 +344,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
         jax.profiler.start_trace(tdir, profiler_options=opts)
+        index.obs.tracer.clear()
+        counts0 = _counters(index.obs)
     drv.recording = True
     drv.gen_s = 0.0
     steps: List[float] = []
@@ -351,6 +369,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         if trace:
             jax.profiler.stop_trace()
         jax.monitoring.unregister_event_duration_listener(counter)
+    engine_counts: Dict[str, int] = {}
+    dropped = 0
+    if trace:
+        engine_counts = {name: v - counts0.get(name, 0)
+                         for name, v in _counters(index.obs).items()}
+        dropped = index.obs.tracer.dropped
+        log(f"engine: {len(index.obs.tracer.spans)} spans in the window, "
+            f"{dropped} dropped")
 
     devices = jax.devices()
     used = devices[:cell.chips]
@@ -418,7 +444,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             f"{1e3 * _p95(steps):.3f} ms; label p95 over {len(labels_t)} "
             f"calls ({int(len(labels_t) * 0.05)} beyond it)")
     run = Run(workload, conf, cell.mix, batch, drv.calls, drv.label_first,
-              tr, peaks)
+              tr, peaks, n_steps, engine_counts, dropped)
     metrics: Dict[str, dict] = {}
     if not trace:
         values = {
