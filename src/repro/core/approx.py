@@ -42,12 +42,12 @@ has no device path of its own.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Set
+from typing import List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from .hashing import GridLSH
-from .soa import _EMPTY_MEMBERS, SoADynamicDBSCAN
+from .soa import _EMPTY_MEMBERS, SoADynamicDBSCAN, _add_grouped
 
 _M64 = (1 << 64) - 1
 _GOLDEN = 0x9E3779B97F4A7C15
@@ -111,11 +111,12 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
         # sampled occupancy per slot — the sizes support runs on; grown
         # in lockstep with _bsize by _ensure_slots
         self._ssize = np.zeros(len(self._bsize), np.int32)
-        # sampled members per slot, maintained alongside _members: the
-        # core-candidate pool crossings/demotions/scans/re-links walk.
-        # Without it every deleted core's border re-links would rescan
-        # full buckets that are mostly non-sampled.
-        self._smembers: Dict[int, Set[int]] = {}
+        # sampled members by slot id: the core-candidate pool crossings,
+        # demotions, scans and re-links walk.  _members holds only the
+        # non-sampled ones here, so that every deleted core's border
+        # re-links need not rescan full buckets that are mostly
+        # non-sampled; the grab replay reads both.
+        self._smembers: List[Optional[Set[int]]] = []
 
     # ------------------------------------------------------------------ #
     # sampling hooks (see SoADynamicDBSCAN; all-true masks at rate=1.0
@@ -127,54 +128,47 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
     def _core_candidate(self, m: int) -> bool:
         return is_sampled(m, self.sample_rate, self.approx_seed)
 
-    def _grab_skip(self, s: int) -> bool:
+    def _grab_skip(self, s: np.ndarray) -> np.ndarray:
         # skip only when every member is a final core: all sampled
         # members core (_ssize >= k_s) and no non-sampled members at all
-        return (self._ssize[s] >= self.core_k
-                and self._bsize[s] == self._ssize[s])
+        return (self._ssize[s] >= self.core_k) & (self._bsize[s]
+                                                 == self._ssize[s])
 
-    def _core_sizes(self, ns: int) -> np.ndarray:
-        return self._ssize[:ns]
+    def _core_sizes(self) -> np.ndarray:
+        return self._ssize
 
     def _core_members(self, s: int) -> Set[int]:
-        return self._smembers.get(s) or _EMPTY_MEMBERS
+        return self._smembers[s] or _EMPTY_MEMBERS
+
+    def _member_sets(self, s: int) -> Tuple[Set[int], ...]:
+        return (self._smembers[s], self._members[s])
 
     def _member_discard(self, s: int, idx: int) -> None:
-        # the full _members sets are never populated here (see
-        # _add_members), so only the sampled view needs updating
         if self._core_candidate(idx):
-            sm = self._smembers.get(s)
-            if sm is not None:
-                sm.discard(idx)
+            self._smembers[s].discard(idx)
+        else:
+            self._members[s].discard(idx)
 
     def _add_members(self, slots: np.ndarray, out: List[int]) -> None:
-        # Deliberately does NOT call super(): every hot-path consumer of
-        # bucket membership goes through _core_members, and occupancy /
-        # emptiness tests read _bsize, so the engine never needs the
-        # full per-slot member sets — maintaining them for the ~9/10
-        # non-sampled points would cost more than the entire sampled
-        # bookkeeping.  _members entries stay as the empty sets
-        # _alloc_slot seeds.
+        # sampled members go to _smembers, the rest to _members: no point
+        # is in two sets
         m = sampled_mask(out, self.sample_rate, self.approx_seed)
-        sub = np.nonzero(m)[0]
-        if not len(sub):
-            return
-        ids_s = [out[j] for j in sub]
-        for i in range(self.t):
-            col = slots[sub, i]
-            order = np.argsort(col, kind="stable")
-            sorted_ids = [ids_s[j] for j in order]
-            cs = col[order]
-            bounds = np.nonzero(cs[1:] != cs[:-1])[0] + 1
-            lo = 0
-            for hi in list(bounds) + [len(cs)]:
-                self._smembers.setdefault(int(cs[lo]), set()).update(
-                    sorted_ids[lo:hi])
-                lo = hi
+        for sets, rows in ((self._smembers, np.nonzero(m)[0]),
+                           (self._members, np.nonzero(~m)[0])):
+            if len(rows):
+                _add_grouped(sets, slots[rows], [out[j] for j in rows])
+
+    def _alloc_slot(self, table: int, key: bytes) -> int:
+        s = super()._alloc_slot(table, key)
+        if s == len(self._smembers):
+            self._smembers.append(set())
+        else:
+            self._smembers[s] = set()
+        return s
 
     def _free_slot(self, s: int) -> None:
         super()._free_slot(s)
-        self._smembers.pop(s, None)
+        self._smembers[s] = None
 
     def _ensure_slots(self, need: int) -> None:
         super()._ensure_slots(need)
@@ -183,21 +177,22 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
                 self._ssize,
                 np.zeros(len(self._bsize) - len(self._ssize), np.int32)])
 
-    def _batch_stats(self, slots: np.ndarray, flat: np.ndarray, ns: int,
-                     smask: Optional[np.ndarray]):
+    def _batch_stats(self, slots: np.ndarray, touched: np.ndarray,
+                     local: np.ndarray, smask: Optional[np.ndarray]):
         """Full occupancy drives membership; sampled occupancy drives
         support."""
+        nu = len(touched)
         rows_s = np.nonzero(smask)[0]
-        delta = np.bincount(flat, minlength=ns).astype(np.int32)
-        self._bsize[:ns] += delta
+        delta = np.bincount(local.ravel(), minlength=nu).astype(np.int32)
+        self._bsize[touched] += delta
         sdelta = np.bincount(
-            slots[rows_s].ravel(), minlength=ns).astype(np.int32)
-        self._ssize[:ns] += sdelta
+            local[rows_s].ravel(), minlength=nu).astype(np.int32)
+        self._ssize[touched] += sdelta
+        core_new = self._ssize[touched]
         supp = np.add.reduce(
-            self._ssize[slots] >= self.core_k, axis=1, dtype=np.int32)
+            core_new[local] >= self.core_k, axis=1, dtype=np.int32)
         supp = np.where(smask, supp, 0).astype(np.int32)
-        core_new = self._ssize[:ns]
-        return core_new - sdelta, core_new, self._ssize[slots], supp
+        return core_new - sdelta, core_new, supp
 
     def _bucket_shrink(self, s: int, idx: int) -> bool:
         self._bsize[s] -= 1
@@ -206,10 +201,10 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
         self._ssize[s] -= 1
         return self._ssize[s] == self.core_k - 1
 
-    def _apply_occupancy_delta(self, dep: np.ndarray, core_dep: np.ndarray,
-                               ns: int) -> None:
-        super()._apply_occupancy_delta(dep, core_dep, ns)
-        self._ssize[:ns] -= core_dep
+    def _apply_occupancy_delta(self, touched: np.ndarray, dep: np.ndarray,
+                               core_dep: np.ndarray) -> None:
+        super()._apply_occupancy_delta(touched, dep, core_dep)
+        self._ssize[touched] -= core_dep
 
     def _rebuild_support(self, slots: np.ndarray,
                          ids: List[int]) -> np.ndarray:
@@ -236,19 +231,22 @@ class SampledCoreDBSCAN(SoADynamicDBSCAN):
         slots = self._slots[rows]
         # 1. occupancy totals: full sizes count every live (point, table)
         #    pair; sampled sizes and the sampled member sets agree and
-        #    carry only sampled live points.  (No full per-slot member
-        #    sets exist to compare _bsize against — see _add_members.)
+        #    carry only sampled live points; the two member sets of a
+        #    slot split its members
         live_slots = np.nonzero(self._bsize[:self._n_slots] > 0)[0]
         assert int(self._bsize[live_slots].sum()) == self.t * len(rows)
         sampled_live = {int(i) for i, smp in zip(ids, m) if smp}
-        for s, sm in self._smembers.items():
+        for s in self._live_slots():
+            sm, um = self._smembers[s], self._members[s]
             assert self._ssize[s] == len(sm), (s, self._ssize[s], len(sm))
             assert sm <= sampled_live, s
+            assert not um & sampled_live, s
+            assert self._bsize[s] == len(sm) + len(um), s
             # 2. buckets with >= k_s sampled members: sampled members core
             if len(sm) >= self.core_k:
                 assert all(y in core_ids for y in sm)
         assert int(self._ssize[:self._n_slots].sum()) == sum(
-            len(v) for v in self._smembers.values())
+            len(v) for v in self._smembers if v is not None)
         assert int(self._ssize[:self._n_slots].sum()) == self.t * len(
             sampled_live)
         supp = np.where(m, np.add.reduce(self._ssize[slots] >= self.core_k,

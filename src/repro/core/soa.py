@@ -49,6 +49,7 @@ them, so the components, and the least-row handles, are the same.
 
 from __future__ import annotations
 
+import itertools
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -61,15 +62,36 @@ _KEY_W = 8  # mixed keys: 2 int32 words per (point, table)
 _EMPTY_MEMBERS: frozenset = frozenset()  # read-only _core_members default
 
 
+def _pow2(n: int) -> int:
+    """The least power of two that holds ``n``, at least one 256-row kernel
+    tile: the device programs see O(log n) shapes rather than one per
+    size."""
+    return max(256, 1 << (n - 1).bit_length())
+
+
 def _pad_rows(a: np.ndarray, fill) -> np.ndarray:
-    """Pad the leading axis to a power of two, at least one 256-row kernel
-    tile, so the device programs see O(log B) batch shapes rather than one
-    per batch size."""
+    """Pad the leading axis to ``_pow2`` of its length."""
     n = len(a)
-    m = max(256, 1 << (n - 1).bit_length())
+    m = _pow2(n)
     if m == n:
         return a
     return np.concatenate([a, np.full((m - n,) + a.shape[1:], fill, a.dtype)])
+
+
+def _add_grouped(sets: List[Optional[Set[int]]], slots: np.ndarray,
+                 ids: Sequence[int]) -> None:
+    """Add ``ids[j]`` to ``sets[slots[j, i]]`` for every table ``i``: one
+    stable argsort per table, one bulk set update per slot."""
+    for i in range(slots.shape[1]):
+        col = slots[:, i]
+        order = np.argsort(col, kind="stable")
+        sorted_ids = [ids[j] for j in order]
+        cs = col[order]
+        bounds = np.nonzero(cs[1:] != cs[:-1])[0] + 1
+        lo = 0
+        for hi in list(bounds) + [len(cs)]:
+            sets[int(cs[lo])].update(sorted_ids[lo:hi])
+            lo = hi
 
 
 class _LiveView:
@@ -151,7 +173,10 @@ class SoADynamicDBSCAN:
         self._slot_key: List[Optional[Tuple[int, bytes]]] = []
         self._bsize = np.zeros(256, np.int32)  # capacity-doubling
         self._n_slots = 0
-        self._members: Dict[int, Set[int]] = {}
+        # members by slot id, None for a free slot.  A list and not a dict:
+        # slots are recycled, so a dict would take a new key for each slot
+        # opened and rehash its whole table every few batches
+        self._members: List[Optional[Set[int]]] = []
         self._free_slots: List[int] = []
 
         self.anchored: Dict[int, Set[int]] = {}
@@ -208,19 +233,20 @@ class SoADynamicDBSCAN:
         if self._free_slots:
             s = self._free_slots.pop()
             self._slot_key[s] = (table, key)
+            self._members[s] = set()
         else:
             s = self._n_slots
             self._slot_key.append((table, key))
+            self._members.append(set())
             self._n_slots += 1
         self._dir[table][key] = s
-        self._members[s] = set()
         return s
 
     def _free_slot(self, s: int) -> None:
         table, key = self._slot_key[s]  # type: ignore[misc]
         del self._dir[table][key]
         self._slot_key[s] = None
-        self._members.pop(s, None)
+        self._members[s] = None
         self._free_slots.append(s)
 
     # ------------------------------------------------------------------ #
@@ -316,26 +342,32 @@ class SoADynamicDBSCAN:
             #    the sampled-core subclass narrows it), and the "core
             #    sizes" the crossings run on are whatever _batch_stats
             #    says drives support — bucket occupancy here, sampled
-            #    occupancy there.
+            #    occupancy there.  Everything per slot is over the slots
+            #    the batch touches, ``touched``, in the batch's local slot
+            #    ids ``local`` (positions in ``touched``, which is sorted,
+            #    so local order is slot order): a batch costs its own
+            #    size, never the directory's.
             with tr.span("soa.insert.hash"):
                 keys32 = self._hash_batch(X)
             with tr.span("soa.insert.resolve_slots"):
                 slots = self._resolve_slots(keys32)
             with tr.span("soa.insert.batch_stats"):
-                ns = self._n_slots
+                touched, inv = np.unique(slots.ravel(), return_inverse=True)
+                local = inv.reshape(slots.shape).astype(np.int32)
                 smask = self._elig_mask(out)
-                core_old, core_new, occ_core, supp_batch = self._batch_stats(
-                    slots, slots.ravel(), ns, smask)
+                core_old, core_new, supp_batch = self._batch_stats(
+                    slots, touched, local, smask)
+                occ_core = core_new[local]
 
             with tr.span("soa.insert.crossings"):
                 # -- threshold crossings: which slots crossed k, and at
                 #    which step
                 crossing = np.nonzero((core_old < k) & (core_new >= k))[0]
-                cross_step = np.full(ns, B + 1, np.int64)  # B+1 = never
+                cross_step = np.full(len(touched), B + 1, np.int64)  # never
                 cross_step[core_new >= k] = -1  # already >= k...
                 if len(crossing):  # ...unless this batch crossed it
                     cross_step[crossing] = self._cross_steps(
-                        crossing, core_old, slots, smask)
+                        crossing, core_old, local, smask)
 
                 # -- existing members of crossing buckets gain support
                 #    (the sequential engine's "bucket crosses: every
@@ -343,7 +375,7 @@ class SoADynamicDBSCAN:
                 promoted_existing: Dict[int, int] = {}  # id -> core_time
                 for s in crossing:
                     step = int(cross_step[s])
-                    for m in self._core_members(int(s)):
+                    for m in self._core_members(int(touched[s])):
                         if not self._core_candidate(m):
                             continue
                         r = self._row[m]
@@ -381,16 +413,15 @@ class SoADynamicDBSCAN:
                 #    max(insert step, bucket cross step); non-core = B+1
                 steps = np.arange(B, dtype=np.int64)[:, None]
                 cand = np.where(occ_core >= k,
-                                np.maximum(cross_step[slots], steps), B + 1)
+                                np.maximum(cross_step[local], steps), B + 1)
                 if smask is not None:
                     cand = np.where(smask[:, None], cand, B + 1)
                 core_time = cand.min(axis=1)
 
             # staged maps id -> batch step, for event-time filtering
             with tr.span("soa.insert.events"):
-                self._apply_insert_events(out, rows, slots, staged,
-                                          core_time, promoted_existing,
-                                          occ_core)
+                self._apply_insert_events(out, slots, staged, core_time,
+                                          promoted_existing)
             self._drop_epoch()
             self._compact_journal()
         return out
@@ -409,66 +440,78 @@ class SoADynamicDBSCAN:
         """May ``m`` ever hold support (be a core point)?"""
         return True
 
-    def _grab_skip(self, s: int) -> bool:
-        """True when bucket ``s`` can hold no grabbable orphan (every
-        member is a final core)."""
+    def _grab_skip(self, s: np.ndarray) -> np.ndarray:
+        """For each slot id in ``s``: True when the bucket can hold no
+        grabbable orphan (every member is a final core)."""
         return self._bsize[s] >= self.core_k
 
-    def _core_sizes(self, ns: int) -> np.ndarray:
-        """The per-slot sizes support thresholds run on (view)."""
-        return self._bsize[:ns]
+    def _core_sizes(self) -> np.ndarray:
+        """The per-slot sizes support thresholds run on, by slot id."""
+        return self._bsize
 
     def _core_members(self, s: int) -> Set[int]:
         """Members of slot ``s`` that may hold support or anchor a border
         — the pool crossings, demotions, scans and re-links walk.  The
         sampled-core subclass narrows it to the sampled members, which is
         what keeps deletion repair O(cores) instead of O(bucket)."""
-        return self._members.get(s) or _EMPTY_MEMBERS
+        return self._members[s] or _EMPTY_MEMBERS
+
+    def _member_sets(self, s: int) -> Tuple[Set[int], ...]:
+        """The sets that together hold every member of slot ``s``."""
+        return (self._members[s],)
 
     def _member_discard(self, s: int, idx: int) -> None:
         """Remove ``idx`` from slot ``s``'s membership (single seam so
         subclasses keep any parallel member structures in sync)."""
         self._members[s].discard(idx)
 
-    def _batch_stats(self, slots: np.ndarray, flat: np.ndarray, ns: int,
-                     smask: Optional[np.ndarray]):
-        """One array pass per insert batch — occupancy deltas + final
-        per-point support, via the device programs (``use_device``) or
-        their bit-exact numpy mirror.  Returns ``(core_old, core_new,
-        occ_core, supp)``: the support-driving slot sizes before/after
-        the batch, their per-(point, table) gather, and each batch
-        point's final support."""
+    def _batch_stats(self, slots: np.ndarray, touched: np.ndarray,
+                     local: np.ndarray, smask: Optional[np.ndarray]):
+        """One array pass per insert batch over the slots it touches —
+        occupancy deltas + final per-point support, via the device
+        programs (``use_device``) or their bit-exact numpy mirror.
+        ``touched`` holds the batch's distinct slot ids, sorted, and
+        ``local`` each (point, table)'s position in it.  Adds the deltas
+        to the occupancy and returns ``(core_old, core_new, supp)``: the
+        support-driving sizes of the touched slots before and after the
+        batch, and each batch point's final support."""
+        nu = len(touched)
         if self.use_device:
             import jax.numpy as jnp
 
             from repro.kernels import ops
 
-            # shapes come from the capacity-doubled arrays, so a stream
-            # compiles O(log n) programs: slots >= ns hold zero and no
-            # slot id reaches them, and the padded rows carry the
-            # out-of-range id ``cap``, which the scatter drops
-            cap = len(self._bsize)
-            slots_p = _pad_rows(slots, cap)
-            jslots = jnp.asarray(slots_p)
-            counts = ops.slot_counts(jslots, n_slots=cap)
+            # the programs run in the batch's local slot space, as long as
+            # the most slots a batch can touch (its entries) padded to a
+            # power of two: the shapes follow the batch size alone, so a
+            # stream compiles O(log n) of them.  Padded rows carry the
+            # out-of-range id ``m``, which the scatter drops, and padded
+            # slots hold zero
+            m = _pow2(local.size)
+            local_p = _pad_rows(local, m)
+            jlocal = jnp.asarray(local_p)
+            counts = ops.slot_counts(jlocal, n_slots=m)
             with self.obs.tracer.span("soa.device.fetch"):
                 counts = np.asarray(counts)
-            delta = counts[:ns]
-            self._bsize[:ns] += delta
+            delta = counts[:nu]
+            self._bsize[touched] += delta
+            sizes = np.zeros(m, np.int32)
+            sizes[:nu] = self._bsize[touched]
             supp, _core = ops.bucket_core_stats(
-                jslots, jnp.asarray(self._bsize), k=self.core_k)
+                jlocal, jnp.asarray(sizes), k=self.core_k)
             with self.obs.tracer.span("soa.device.fetch"):
                 supp = np.asarray(supp)
             if self.obs.enabled:
-                self._count_copies((slots_p, self._bsize), (counts, supp))
+                self._count_copies((local_p, sizes), (counts, supp))
             supp = supp[:len(slots)]
+            new_sizes = sizes[:nu]
         else:
-            delta = np.bincount(flat, minlength=ns).astype(np.int32)
-            self._bsize[:ns] += delta
+            delta = np.bincount(local.ravel(), minlength=nu).astype(np.int32)
+            self._bsize[touched] += delta
+            new_sizes = self._bsize[touched]
             supp = np.add.reduce(
-                self._bsize[slots] >= self.core_k, axis=1, dtype=np.int32)
-        new_sizes = self._bsize[:ns]
-        return new_sizes - delta, new_sizes, self._bsize[slots], supp
+                new_sizes[local] >= self.core_k, axis=1, dtype=np.int32)
+        return new_sizes - delta, new_sizes, supp
 
     def _cross_steps(self, crossing: np.ndarray, core_old: np.ndarray,
                      slots: np.ndarray,
@@ -476,7 +519,8 @@ class SoADynamicDBSCAN:
         """Batch step at which each crossing slot reached size k: the
         (k - old_size)-th core-eligible arrival into the slot this batch.
         One stable argsort of the flat slot list; within a slot the order
-        is by flat position, i.e. by batch step."""
+        is by flat position, i.e. by batch step.  ``crossing`` and
+        ``core_old`` index the same slot space as ``slots``."""
         if smask is None:
             flat = slots.ravel()
             rows_map = None
@@ -491,148 +535,119 @@ class SoADynamicDBSCAN:
         return steps if rows_map is None else rows_map[steps]
 
     def _add_members(self, slots: np.ndarray, out: List[int]) -> None:
-        for i in range(self.t):
-            col = slots[:, i]
-            order = np.argsort(col, kind="stable")
-            sorted_ids = [out[j] for j in order]
-            cs = col[order]
-            bounds = np.nonzero(cs[1:] != cs[:-1])[0] + 1
-            lo = 0
-            for hi in list(bounds) + [len(cs)]:
-                self._members[int(cs[lo])].update(sorted_ids[lo:hi])
-                lo = hi
+        _add_grouped(self._members, slots, out)
 
     # ------------------------------------------------------------------ #
     # insert-time events: promotions, orphan grabs, border scans
     # ------------------------------------------------------------------ #
-    def _apply_insert_events(self, out, rows, slots, step_of, core_time,
-                             promoted_existing, occ_final) -> None:
+    def _apply_insert_events(self, out, slots, step_of, core_time,
+                             promoted_existing) -> None:
         """Replay the sequential engine's attachment decisions by event
         time (see module docstring).  All final-core batch points record a
         promotion; final-non-core batch points scan their buckets'
         cores-at-insert-time; promoted cores grab unattached orphans from
         their sub-threshold buckets at their promotion time."""
-        k, B = self.k, len(out)
-        INF = B + 1
+        B, t = len(out), self.t
 
-        # promotion events: (time, id, slots_row) — batch cores + promoted
+        # promotion events: (time, id, slots row) — batch cores + promoted
         # existing, exactly the sequential engine's sorted(promoted) sets
-        events: List[Tuple[int, int, np.ndarray]] = []
         ctime: Dict[int, int] = {}
         core_js = np.nonzero(core_time <= B)[0]
-        for j in core_js:
-            ct = int(core_time[j])
-            ctime[out[j]] = ct
-            events.append((ct, out[j], slots[j]))
-        for m, ct in promoted_existing.items():
+        for j in core_js.tolist():
+            ctime[out[j]] = int(core_time[j])
+        pe_rows = np.empty(len(promoted_existing), np.int64)
+        for i, (m, ct) in enumerate(promoted_existing.items()):
             ctime[m] = ct
-            r = self._row[m]
+            r = pe_rows[i] = self._row[m]
             old = int(self._attach[r]) if self._attach[r] >= 0 else None
             self._record(m, old, m)  # promotion delta (old = pre-batch)
             if old is not None:
                 self.anchored[old].discard(m)
                 self._attach[r] = -1
-            events.append((ct, m, self._slots[r]))
-        for j in core_js:
+        for j in core_js.tolist():
             self._record(out[j], None, out[j])
-        self.n_promotions += len(events)
-
-        # helper: is m core at time s (strictly before)?  -1 = pre-batch
+        self.n_promotions += len(ctime)
         support = self._support
         row = self._row
-
-        def _core_at(m: int, s: int) -> bool:
-            ct = ctime.get(m)
-            if ct is not None:
-                return ct < s
-            return support[row[m]] > 0 and m not in step_of
 
         # -- grab events: promoted core c, sub-threshold bucket, orphan y.
         # Orphan status (final support 0, no pre-batch anchor) is constant
         # through the replay — attachments only apply at the end — so the
-        # loop is inverted: one vectorised sweep finds every orphan row,
-        # and each orphan binary-searches its own slots' time-sorted event
-        # lists for the earliest grab after its insertion.  A slot's list
-        # is sorted by (time, id), so the first event with ct > step IS
-        # min(ct, c) over that slot's qualifying grabs.  No orphan can
-        # live in a slot the old per-slot walk skipped (all-core buckets
-        # give every member support > 0), so no skip test is needed.
+        # loop is inverted: the orphans are found once, and each (orphan,
+        # slot) pair searches its slot's events, sorted by (time, id), for
+        # the first one after the orphan's insertion, which IS min(ct, c)
+        # over that slot's qualifying grabs; the orphan takes the least
+        # over its slots.  Only an orphan that shares a bucket with a
+        # promotion can be grabbed, and only in a bucket that
+        # ``_grab_skip`` leaves open, so the candidates are the members of
+        # the events' open slots: fewer than k each in the exact engine,
+        # wherever the window's orphans are.
         best: Dict[int, Tuple[int, int]] = {}
-        cand = (np.nonzero((support == 0) & (self._attach < 0)
-                           & (self._ids != -1))[0]
-                if self.attach_orphans and events else ())
-        if len(cand):  # no orphans (dense exact case): skip event scatter
-            tmp: Dict[int, List[Tuple[int, int]]] = {}
-            for ct, c, srow in events:
-                for s in srow:
-                    tmp.setdefault(int(s), []).append((ct, c))
-            evs_ct: Dict[int, np.ndarray] = {}
-            evs_c: Dict[int, np.ndarray] = {}
-            for s, lst in tmp.items():
-                lst.sort()
-                evs_ct[s] = np.fromiter((t for t, _ in lst), np.int64,
-                                        len(lst))
-                evs_c[s] = np.fromiter((c for _, c in lst), np.int64,
-                                       len(lst))
-            # only orphans sharing a bucket with a promotion can be
-            # grabbed — with a stable core set (sampled tier) this drops
-            # the persistent-noise sweep to near nothing
-            ev_slots = np.fromiter(tmp, np.int64, len(tmp))
-            ev_slots.sort()
-            touch = np.isin(self._slots[cand], ev_slots).any(axis=1)
-            cand = cand[touch]
+        cand: np.ndarray = np.zeros(0, np.int64)
+        grab_rows = 0
+        if self.attach_orphans and ctime:
+            ev_s = np.concatenate([slots[core_js], self._slots[pe_rows]])
+            ev_slots = np.unique(ev_s)
+            pool: Set[int] = set()
+            for s in ev_slots[~self._grab_skip(ev_slots)].tolist():
+                for mem in self._member_sets(s):
+                    pool.update(mem)
+            grab_rows = len(pool)
+            if pool:
+                rows_p = np.fromiter(map(row.__getitem__, pool), np.int64,
+                                     len(pool))
+                cand = np.sort(rows_p[(support[rows_p] == 0)
+                                      & (self._attach[rows_p] < 0)])
         if len(cand):
             n_orph = len(cand)
             ids_c = self._ids[cand]
             steps = np.fromiter(
-                (step_of.get(int(y), -1) for y in ids_c),
-                np.int64, n_orph)
-            S = self._slots[cand]                       # (n_orph, t)
-            INF2 = np.iinfo(np.int64).max
-            best_ct = np.full(n_orph, INF2, np.int64)
-            best_c = np.full(n_orph, INF2, np.int64)
-            # group (orphan, slot) pairs by slot: one bulk search per
-            # slot instead of one Python bisect per pair
-            flat = S.ravel()
-            oidx = np.repeat(np.arange(n_orph), S.shape[1])
-            order = np.argsort(flat, kind="stable")
-            fs, fo = flat[order], oidx[order]
-            cuts = np.nonzero(np.diff(fs))[0] + 1
-            starts = np.concatenate([[0], cuts])
-            ends = np.concatenate([cuts, [len(fs)]])
-            for a, b in zip(starts, ends):
-                s = int(fs[a])
-                ect = evs_ct.get(s)
-                if ect is None:
-                    continue
-                g = fo[a:b]
-                pos = np.searchsorted(ect, steps[g], side="right")
-                q = pos < len(ect)
-                if not q.any():
-                    continue
-                g2, p2 = g[q], pos[q]
-                ct2, c2 = ect[p2], evs_c[s][p2]
-                upd = (ct2 < best_ct[g2]) | ((ct2 == best_ct[g2])
-                                             & (c2 < best_c[g2]))
-                if upd.any():
-                    gi = g2[upd]
-                    best_ct[gi] = ct2[upd]
-                    best_c[gi] = c2[upd]
-            for i in np.nonzero(best_ct < INF2)[0]:
-                best[int(ids_c[i])] = (int(best_ct[i]),
-                                       int(best_c[i]))
+                (step_of.get(y, -1) for y in ids_c.tolist()), np.int64,
+                n_orph)
+            qs = self._slots[cand].ravel()            # (orphan, slot) pairs
+            qo = np.repeat(np.arange(n_orph), t)
+            # every event in a slot that an orphan sits in, sorted by
+            # (slot, time, id); times lie in [0, B], so slot * C + time
+            # with C = B + 2 orders entries as (slot, time) does
+            ev_c = np.concatenate([np.asarray(out, np.int64)[core_js],
+                                   np.fromiter(promoted_existing, np.int64,
+                                               len(promoted_existing))])
+            ev_ct = np.fromiter((ctime[c] for c in ev_c.tolist()), np.int64,
+                                len(ev_c))
+            fs = ev_s.ravel()
+            keep = np.isin(fs, qs)
+            fs = fs[keep]
+            fct = np.repeat(ev_ct, t)[keep]
+            fc = np.repeat(ev_c, t)[keep]
+            o = np.lexsort((fc, fct, fs))
+            fs, fct, fc = fs[o], fct[o], fc[o]
+            C = np.int64(B + 2)
+            pos = np.searchsorted(fs.astype(np.int64) * C + fct,
+                                  qs.astype(np.int64) * C + steps[qo] + 1)
+            ok = pos < len(fs)
+            ok[ok] = fs[pos[ok]] == qs[ok]
+            go, gct, gc = qo[ok], fct[pos[ok]], fc[pos[ok]]
+            o = np.lexsort((gc, gct, go))
+            go, gct, gc = go[o], gct[o], gc[o]
+            first = np.ones(len(go), bool)
+            first[1:] = go[1:] != go[:-1]
+            for i, ct, c in zip(go[first].tolist(), gct[first].tolist(),
+                                gc[first].tolist()):
+                best[int(ids_c[i])] = (ct, c)
 
         # -- scan events: final-non-core batch points attach at insert.
         # A point m answers a scan at step j iff it is core strictly
         # before j: core time max(core_time_m, insert_step_m), with -1
         # for pre-batch cores.  Bulk form of "for each border, first
-        # table whose slot holds such an m": one vectorised candidate
-        # build over the final core set (restricted to the slots borders
-        # actually touch), lexsorted by (slot, time, id) so a per-slot
-        # slice is a time-sorted prefix-min table; then one grouped
-        # searchsorted per touched slot.  This is the hot path when most
-        # of a batch is non-core (approx tier); the exact engine's dense
-        # case has no scan events at all.
+        # table whose slot holds such an m": the candidate pool is the
+        # final cores among the core members of the slots borders touch
+        # (fewer than k each in the exact engine, since a border's
+        # buckets are all sub-threshold), lexsorted by (slot, time, id)
+        # so a per-slot slice is a time-sorted prefix-min table; then one
+        # grouped searchsorted per touched slot.  This is the hot path
+        # when most of a batch is non-core (approx tier, sparse data);
+        # the exact engine's dense case has no scan events at all.
+        scan_rows = 0
         borders = np.nonzero(core_time > B)[0]
         if len(borders):
             nb, tw = len(borders), slots.shape[1]
@@ -644,27 +659,34 @@ class SoADynamicDBSCAN:
             tpos = np.tile(np.arange(tw), nb)
             # a slot with no core-candidate members can never answer a
             # scan — drops most fringe buckets in the sampled subclass
-            csz = self._core_sizes(self._n_slots)
-            keep = csz[flatb] > 0
+            keep = self._core_sizes()[flatb] > 0
             flatb, bidx, tpos = flatb[keep], bidx[keep], tpos[keep]
         if len(borders) and len(flatb):
             needed = np.unique(flatb)
-            # candidate pool: every final core (batch promotions carry
-            # their event time; pre-batch cores time -1).  Batch points
-            # with final support are always in ctime, so the override
-            # loop below touches promotion events only.
-            rowsE = np.nonzero((support > 0) & (self._ids != -1))[0]
-            timesE = np.full(len(rowsE), -1, np.int64)
-            for m, ct in ctime.items():
-                st = step_of.get(m, -1)
-                p = int(np.searchsorted(rowsE, row[m]))
-                timesE[p] = ct if ct > st else st
-            flatE = self._slots[rowsE].ravel()
-            idsR = np.repeat(self._ids[rowsE], tw)
-            timesR = np.repeat(timesE, tw)
-            inn = np.isin(flatE, needed)
-            flatE, idsR, timesR = flatE[inn], idsR[inn], timesR[inn]
-        if len(borders) and len(flatb) and len(flatE):
+            mems = [self._core_members(s) for s in needed.tolist()]
+            sizes = np.fromiter(map(len, mems), np.int64, len(mems))
+            pool_ids = list(itertools.chain.from_iterable(mems))
+            idsR = np.fromiter(pool_ids, np.int64, len(pool_ids))
+            rowsR = np.fromiter(map(row.__getitem__, pool_ids), np.int64,
+                                len(pool_ids))
+            core = support[rowsR] > 0
+            flatE = np.repeat(needed, sizes)[core]
+            idsR = idsR[core]
+            scan_rows = len(idsR)
+            # batch promotions carry their event time, pre-batch cores -1
+            timesR = np.full(len(idsR), -1, np.int64)
+            if ctime and len(idsR):
+                ev_ids = np.fromiter(ctime, np.int64, len(ctime))
+                ev_t = np.fromiter(
+                    (max(ct, step_of.get(m, -1)) for m, ct in ctime.items()),
+                    np.int64, len(ctime))
+                o = np.argsort(ev_ids)
+                ev_ids, ev_t = ev_ids[o], ev_t[o]
+                p = np.minimum(np.searchsorted(ev_ids, idsR),
+                               len(ev_ids) - 1)
+                hit = ev_ids[p] == idsR
+                timesR[hit] = ev_t[p[hit]]
+        if len(borders) and len(flatb) and scan_rows:
             orderE = np.lexsort((idsR, timesR, flatE))
             fsE, tsE, msE = flatE[orderE], timesR[orderE], idsR[orderE]
             # per-slot running min of candidate id in time order, with no
@@ -704,6 +726,9 @@ class SoADynamicDBSCAN:
             self.anchored.setdefault(c, set()).add(y)
             self._record(y, None, c)
         self.n_grab_events += len(best)
+        if self.obs.enabled:
+            self.obs.counter("soa.replay.grab_rows").inc(grab_rows)
+            self.obs.counter("soa.replay.scan_rows").inc(scan_rows)
 
     # ------------------------------------------------------------------ #
     # deletion (sequential mirror of DynamicDBSCAN.delete_point; the
@@ -737,15 +762,15 @@ class SoADynamicDBSCAN:
                 self._compact_journal()
                 return
             with tr.span("soa.expire.departures"):
-                slots_d, cross_at, dep, new_sizes = self._departures(ids)
+                slots_d, cross_at, emptied = self._departures(ids)
             with tr.span("soa.expire.replay"):
                 pending = self._replay_deletes(ids, slots_d, cross_at)
             with tr.span("soa.expire.relink"):
                 self._relink_pending(pending)
                 # emptied slots free once, at the end (their member sets
                 # emptied exactly when the final size reached zero)
-                for s in np.nonzero((dep > 0) & (new_sizes == 0))[0]:
-                    self._free_slot(int(s))
+                for s in emptied.tolist():
+                    self._free_slot(s)
             self._drop_epoch()
             self._compact_journal()
 
@@ -757,25 +782,28 @@ class SoADynamicDBSCAN:
             self._comp = None
 
     def _departures(self, ids: List[int]):
-        """The batch's array pass: each point's slots, the slots whose
-        support-driving size falls below the threshold keyed by the batch
-        step at which it does, and the occupancy decrement, applied."""
+        """The batch's array pass over the slots it touches: each point's
+        slots, the slots whose support-driving size falls below the
+        threshold keyed by the batch step at which it does, and the slots
+        the batch empties (sorted); the occupancy decrement is applied."""
         k, t, D = self.core_k, self.t, len(ids)
         rows_d = np.fromiter((self._row[i] for i in ids), np.int64, D)
         slots_d = self._slots[rows_d]                  # (D, t)
-        ns = self._n_slots
-        flat_d = slots_d.ravel()
-        dep = np.bincount(flat_d, minlength=ns).astype(np.int32)
+        # local slot ids: positions in the sorted touched slots, so local
+        # order is slot order
+        touched, flat_d = np.unique(slots_d.ravel(), return_inverse=True)
+        nu = len(touched)
+        dep = np.bincount(flat_d, minlength=nu).astype(np.int32)
         smask = self._elig_mask(ids)  # same eligibility as on insert
         if smask is None:
             core_dep, core_flat, rows_map = dep, flat_d, None
         else:
             rows_map = np.nonzero(smask)[0]
-            core_flat = slots_d[rows_map].ravel()
-            core_dep = np.bincount(core_flat, minlength=ns).astype(np.int32)
-        core_old = self._core_sizes(ns).copy()
+            core_flat = flat_d.reshape(D, t)[rows_map].ravel()
+            core_dep = np.bincount(core_flat, minlength=nu).astype(np.int32)
+        core_old = self._core_sizes()[touched]
         core_new_sz = core_old - core_dep
-        new_sizes = self._bsize[:ns] - dep
+        emptied = touched[self._bsize[touched] == dep]
 
         # threshold down-crossings: the (old - k + 1)-th core-eligible
         # departure drops the slot's core size below k, at that step
@@ -789,11 +817,11 @@ class SoADynamicDBSCAN:
             steps = order[entry] // t
             if rows_map is not None:
                 steps = rows_map[steps]
-            for s, j in zip(cross_slots, steps):
-                cross_at.setdefault(int(j), []).append(int(s))
+            for s, j in zip(touched[cross_slots].tolist(), steps.tolist()):
+                cross_at.setdefault(j, []).append(s)
 
-        self._apply_occupancy_delta(dep, core_dep, ns)
-        return slots_d, cross_at, dep, new_sizes
+        self._apply_occupancy_delta(touched, dep, core_dep)
+        return slots_d, cross_at, emptied
 
     def _replay_deletes(self, ids: List[int], slots_d: np.ndarray,
                         cross_at: Dict[int, List[int]]) -> Set[int]:
@@ -872,10 +900,11 @@ class SoADynamicDBSCAN:
                     self._record(y, None, c)
                     break
 
-    def _apply_occupancy_delta(self, dep: np.ndarray, core_dep: np.ndarray,
-                               ns: int) -> None:
-        """Batched occupancy decrement (delete mirror of _batch_stats)."""
-        self._bsize[:ns] -= dep
+    def _apply_occupancy_delta(self, touched: np.ndarray, dep: np.ndarray,
+                               core_dep: np.ndarray) -> None:
+        """Batched occupancy decrement of the distinct slots ``touched``
+        (delete mirror of _batch_stats)."""
+        self._bsize[touched] -= dep
 
     def _delete_one(self, idx: int) -> None:
         if idx not in self._row:
@@ -1112,14 +1141,18 @@ class SoADynamicDBSCAN:
         if not self._journal:
             return
         with self.obs.tracer.span("soa.journal.compact"):
-            merged: Dict[int, List[Optional[int]]] = {}
+            # each id's first old and last new, in first-record order; two
+            # flat dicts and not a list per id, so that a collection of the
+            # young generation mid-compaction finds no thousands of live
+            # containers to promote into the oldest one
+            first: Dict[int, Optional[int]] = {}
+            last: Dict[int, Optional[int]] = {}
             for idx, old, new in self._journal:
-                if idx in merged:
-                    merged[idx][1] = new
-                else:
-                    merged[idx] = [old, new]
-            self._journal = [(i, o, n) for i, (o, n) in merged.items()
-                             if o != n]
+                if idx not in first:
+                    first[idx] = old
+                last[idx] = new
+            self._journal = [(i, o, last[i]) for i, o in first.items()
+                             if o != last[i]]
 
     def drain_deltas(self) -> List[Tuple[int, Optional[int], Optional[int]]]:
         if self._journal is None:
@@ -1234,7 +1267,8 @@ class SoADynamicDBSCAN:
         rows = np.fromiter(self._row.values(), np.int64, len(self._row))
         ids = np.fromiter(self._row.keys(), np.int64, len(self._row))
         if len(rows) == 0:
-            assert not self._members  # every bucket freed when it emptied
+            # every bucket freed when it emptied
+            assert all(m is None for m in self._members)
             return
         core_ids = {int(i) for i, r in zip(ids, rows)
                     if self._support[r] > 0}
@@ -1260,7 +1294,7 @@ class SoADynamicDBSCAN:
                 for s in self._slots[r]:
                     # cores are always core-candidates, so the candidate
                     # pool view suffices (and stays valid for the
-                    # sampled subclass, which keeps no full membership)
+                    # sampled subclass, which keeps them apart)
                     mem = self._core_members(int(s))
                     assert not (mem & core_ids) - {i}, (i, int(s))
         # 4. anchored maps mirror attach exactly
@@ -1269,11 +1303,14 @@ class SoADynamicDBSCAN:
             (self._support[rows] == 0) & (self._attach[rows] >= 0)))
         # 5. every core pair sharing a bucket shares a component (Thm 2)
         comp = self._ensure_comp()
-        for s in list(self._members):
+        for s in self._live_slots():
             cs = [m for m in self._core_members(s) if m in core_ids]
             if len(cs) > 1:
                 h0 = comp[self._row[cs[0]]]
                 assert all(comp[self._row[c]] == h0 for c in cs[1:])
+
+    def _live_slots(self) -> List[int]:
+        return [s for s, key in enumerate(self._slot_key) if key is not None]
 
     def _check_counts(self, rows: np.ndarray, ids: np.ndarray,
                       core_ids: Set[int]) -> None:
@@ -1282,7 +1319,8 @@ class SoADynamicDBSCAN:
         assert np.array_equal(
             np.add.reduce(occ >= self.core_k, axis=1), self._support[rows])
         # 2. bucket sizes match membership; >=k buckets are all-core
-        for s, mem in self._members.items():
+        for s in self._live_slots():
+            mem = self._members[s]
             assert self._bsize[s] == len(mem), (s, self._bsize[s], len(mem))
             if len(mem) >= self.core_k:
                 assert all(m in core_ids for m in mem)

@@ -60,9 +60,11 @@ def test_lsh_hash_compiles_to_a_mosaic_kernel(one_chip, d):
     assert compiled.out_info.shape == (1024, T, 2)
 
 
-# slot capacities double; the paper stream (200k points x 10 tables) can
-# create up to 2M buckets, so 2^21 is the largest capacity it reaches
-@pytest.mark.parametrize("cap", [2**17, 2**21])
+# the engine calls them in a batch's local slot space: a 1,000-point batch
+# of t=10 pads to 1,024 rows and 2^14 slots.  Larger slot vectors compile
+# too, up to 2^21, the most buckets the paper stream (200k points x 10
+# tables) can create
+@pytest.mark.parametrize("cap", [2**14, 2**17, 2**21])
 def test_occupancy_and_support_programs_compile(one_chip, cap):
     slots = _shape(one_chip, (1024, T), jnp.int32)
     counts = ops.slot_counts.lower(slots, n_slots=cap).compile()

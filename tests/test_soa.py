@@ -315,3 +315,117 @@ def test_device_epoch_matches_host_epoch(case, orphans):
         assert rounds[-1] == 0 and set(labels.values()) == {NOISE}
     else:
         assert caps[0] == (256, 256) and caps[1][0] > 256 < caps[1][1]
+
+
+# ---------------------------------------------------------------------- #
+# sparse, noise-heavy data: the grab and scan replays, and per-batch work
+# sized by the batch
+# ---------------------------------------------------------------------- #
+def _mixture(n, d, seed, noise=0.1):
+    """Zipf-weighted Gaussian clusters of differing spread with a share of
+    uniform background noise, standardised: buckets of every size, most
+    of them sparse, and cores, borders and noise side by side."""
+    rng = np.random.default_rng([seed, 1])
+    model = np.random.default_rng(0)
+    centers = model.uniform(-3.5, 3.5, (5, d))
+    w = 1.0 / np.arange(1, 6)
+    stds = model.uniform(0.15, 0.5, 5)
+    lab = rng.choice(5, size=n, p=w / w.sum())
+    X = centers[lab] + rng.normal(size=(n, d)) * stds[lab, None]
+    rows = rng.random(n) < noise
+    X[rows] = rng.uniform(-4.5, 4.5, (int(rows.sum()), d))
+    return (X - X.mean(0)) / X.std(0)
+
+
+SPARSE = dict(d=6, k=10, t=10, eps=0.3, seed=0)
+
+
+@pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
+def test_sparse_noisy_batches_match_dynamic(backend):
+    """A sliding window over sparse, noisy data, where the grab and scan
+    replays run on every insert: after every insert and expire batch the
+    labels, every point's anchor and the drained deltas are dynamic's."""
+    X = _mixture(2600, SPARSE["d"], seed=3)
+    cfg = ClusterConfig(**SPARSE, sample_rate=1.0)
+    ref = build_index(cfg.replace(backend="dynamic"))
+    ix = build_index(cfg.replace(backend=backend))
+    ref.drain_deltas(), ix.drain_deltas()
+    alive: list = []
+    for lo in range(0, len(X), 200):
+        got = ix.insert_batch(X[lo:lo + 200])
+        assert got == ref.insert_batch(X[lo:lo + 200])
+        alive += got
+        steps = [ix.drain_deltas]
+        if len(alive) > 1000:
+            dels, alive = alive[:200], alive[200:]
+            steps.append(lambda: (ix.delete_batch(dels), ref.delete_batch(
+                dels), ix.drain_deltas())[-1])
+        for step in steps:
+            deltas = step()
+            assert sorted(deltas) == sorted(ref.drain_deltas())
+            assert ix.labels() == ref.labels()
+            assert ([ix.core_anchor_of(i) for i in alive]
+                    == [ref.core_anchor_of(i) for i in alive])
+    ix.check_invariants()
+    assert ix.engine.n_grab_events > 0 and ix.engine.n_scan_events > 0
+
+
+@pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
+def test_batch_allocates_less_than_one_directory_array(backend):
+    """With a directory of 2^20 slots, one 1,000-point insert and one
+    expiry each allocate less than a single int32 array of the directory's
+    size: the batch's work is over its own rows, slots and members.  The
+    window is a fixed set of hot spots beside sparse noise that holds
+    most of the slots; the batches fall on the hot spots, so what they
+    add to the directory (a member set per new bucket) stays small."""
+    import tracemalloc
+
+    rng = np.random.default_rng(0)
+    centers = rng.uniform(-40, 40, (5, 4))
+
+    def hot(n):
+        return centers[rng.integers(0, 5, n)] + rng.normal(0, 0.08, (n, 4))
+
+    ix = build_index(ClusterConfig(backend=backend, d=4, k=3, t=10,
+                                   eps=0.05, seed=0))
+    ids = []
+    for _ in range(3):
+        ids += ix.insert_batch(hot(1000))
+    for _ in range(52):  # one bucket per point and table
+        ids += ix.insert_batch(rng.uniform(-50, 50, (1000, 4)))
+    eng = ix.engine
+    assert len(eng._bsize) == 1 << 20 and eng._n_slots > 1 << 19
+    directory = 4 * len(eng._bsize)
+    X = hot(1000)
+    grabs, scans = eng.n_grab_events, eng.n_scan_events
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        ids += ix.insert_batch(X)
+        add_peak = tracemalloc.get_traced_memory()[1] - base
+        tracemalloc.reset_peak()
+        base = tracemalloc.get_traced_memory()[0]
+        ix.delete_batch(ids[:1000])
+        del_peak = tracemalloc.get_traced_memory()[1] - base
+    finally:
+        tracemalloc.stop()
+    assert add_peak < directory and del_peak < directory
+    assert eng.n_grab_events > grabs and eng.n_scan_events > scans
+
+
+def test_replay_rows_do_not_grow_with_the_window():
+    """The grab and scan replays examine the members of the batch's own
+    buckets, so the same batch into a window ten times larger examines
+    about as many rows."""
+    batch = _mixture(1000, SPARSE["d"], seed=99)
+    rows = []
+    for window in (2000, 20000):
+        ix = build_index(ClusterConfig(backend="soa", obs=True, **SPARSE))
+        ix.insert_batch(_mixture(window, SPARSE["d"], seed=window))
+        before = ix.obs.snapshot()["metrics"]
+        ix.insert_batch(batch)
+        after = ix.obs.snapshot()["metrics"]
+        rows.append(sum(after[n]["value"] - before[n]["value"]
+                        for n in ("soa.replay.grab_rows",
+                                  "soa.replay.scan_rows")))
+    assert min(rows) > 0 and max(rows) < 2 * min(rows), rows

@@ -48,6 +48,10 @@ REBUILD = {("soa.rebuild", None), ("soa.rebuild.edges", "soa.rebuild"),
            ("soa.device.fetch", "soa.rebuild.sv")}
 
 
+# the rows the insert's event replay examined: grab and scan candidates
+REPLAY = ("soa.replay.grab_rows", "soa.replay.scan_rows")
+
+
 def test_insert_expire_label_span_tree():
     ix = build_index(cfg())
     ix.drain_deltas()  # start the change feed, so the journal compacts
@@ -86,13 +90,16 @@ def test_copy_bytes_match_the_padded_shapes():
     # it holds every slot the batch could open (0 + 300 * t = 2,400)
     rows, cap = 512, 4096
     assert len(ix.engine._bsize) == cap
+    # the occupancy programs run in the batch's local slot space: as many
+    # slots as the batch has entries (300 * t = 2,400), to a power of two
+    local = 4096
     h2d = (rows * D * 4          # points, f32
            + T * 4               # eta, f32
            + 2 * T * D * 4       # mixers, int32
-           + rows * T * 4        # slots, int32
-           + cap * 4)            # slot sizes, int32
+           + rows * T * 4        # local slot ids, int32
+           + local * 4)          # touched slots' sizes, int32
     d2h = (rows * T * 2 * 4      # keys, two int32 words
-           + cap * 4             # occupancy delta, int32
+           + local * 4           # occupancy delta, int32
            + rows * 4)           # support, int32
     m = ix.obs.snapshot()["metrics"]
     assert m["soa.h2d_bytes"]["value"] == h2d
@@ -141,7 +148,7 @@ def test_rebuild_counts_sv_rounds_and_edges():
     # every epoch of soa-device is computed on the device
     assert m["soa.rebuild.device"]["value"] == ix.engine.n_epoch_rebuilds
     assert set(m) == {"soa.h2d_bytes", "soa.d2h_bytes", "soa.sv_rounds",
-                      "soa.rebuild.device"}
+                      "soa.rebuild.device"} | set(REPLAY)
 
 
 def test_host_engine_rebuilds_on_the_host():
@@ -158,7 +165,20 @@ def test_host_engine_rebuilds_on_the_host():
     m = ix.obs.snapshot()["metrics"]
     assert m["soa.sv_rounds"]["value"] == sum(
         s["args"]["rounds"] for s in rebuilds)
-    assert set(m) == {"soa.sv_rounds"}
+    assert set(m) == {"soa.sv_rounds"} | set(REPLAY)
+
+
+@pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
+def test_replay_counters_exist_only_with_obs(backend):
+    """Every insert counts the rows its replay examined, 0 included, so
+    both counters exist once ``obs`` is on; with it off there are none."""
+    on = build_index(cfg(backend, obs=True))
+    off = build_index(cfg(backend, obs=False))
+    for ix in (on, off):
+        ix.insert_batch(points(40))
+    m = on.obs.snapshot()["metrics"]
+    assert all(m[name]["value"] >= 0 for name in REPLAY)
+    assert off.obs.snapshot()["metrics"] == {}
 
 
 @pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
