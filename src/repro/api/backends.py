@@ -177,6 +177,7 @@ class SoAIndex(ClusterIndex):
     def stats(self):
         return {
             "n_epoch_rebuilds": self.engine.n_epoch_rebuilds,
+            "n_epoch_merges": self.engine.n_epoch_merges,
             "n_promotions": self.engine.n_promotions,
             "n_demotions": self.engine.n_demotions,
             "n_grab_events": self.engine.n_grab_events,

@@ -35,16 +35,21 @@ states (a point grabbed mid-batch and promoted later the same batch)
 cancel out of the final configuration and of the compacted journal, so
 they are skipped rather than simulated.
 
-Connectivity is rebuilt per *epoch* (any mutation invalidates, first
-query rebuilds): chain edges are consecutive core rows per slot, and the
-component labelling is a vectorised Shiloach–Vishkin hook+shortcut pass
-(the data-parallel connectivity of Wang et al.'s parallel DBSCAN) — no
-scipy dependency, O(E log n) array work, amortised across every label
-query in the epoch.  With ``use_device`` the epoch is one device program
-instead (``ops.core_components``): the same hook+shortcut over the much
-smaller bucket graph, where each core row joins its ``t`` slots — two
-cores share a component exactly when a path of shared buckets joins
-them, so the components, and the least-row handles, are the same.
+Connectivity is cached per *epoch*: chain edges are consecutive core
+rows per slot, and the component labelling is a vectorised
+Shiloach–Vishkin hook+shortcut pass (the data-parallel connectivity of
+Wang et al.'s parallel DBSCAN) — no scipy dependency, O(E log n) array
+work, amortised across every label query in the epoch.  With
+``use_device`` the epoch is one device program instead
+(``ops.core_components``): the same hook+shortcut over the much smaller
+bucket graph, where each core row joins its ``t`` slots — two cores share
+a component exactly when a path of shared buckets joins them, so the
+components, and the least-row handles, are the same.  A delete can split
+a component, so it drops the epoch and the first query rebuilds it.  An
+insert only raises support: it adds core rows, whose slots are fixed,
+and demotes none, so components only grow and merge.  The epoch is kept
+across inserts, and the first query after them folds the new core rows
+into it on the host, over the few components their slots touch.
 """
 
 from __future__ import annotations
@@ -183,14 +188,23 @@ class SoADynamicDBSCAN:
         self._next_idx = 0
         self._journal: Optional[
             List[Tuple[int, Optional[int], Optional[int]]]] = None
-        # epoch cache: row -> component handle for core rows (None = dirty)
+        # epoch cache: by row, the least core row of the row's component
+        # for core rows, -1 for the rest (None = dirty); the component's
+        # handle is the id of that row
         self._comp: Optional[np.ndarray] = None
+        # by slot, a core row of the slot's component (-1 where the slot
+        # holds no core row): where a merge finds a slot's component
+        self._slot_rep: Optional[np.ndarray] = None
+        # rows that became core since the epoch, for the next query to fold
+        # into it
+        self._pending: List[np.ndarray] = []
         # the device epoch's renumbered slot matrix, kept across epochs so
         # that each rebuild writes into pages already mapped
         self._ranked: Optional[np.ndarray] = None
 
         # instrumentation (adapter stats())
         self.n_epoch_rebuilds = 0
+        self.n_epoch_merges = 0
         self.n_promotions = 0
         self.n_demotions = 0
         self.n_grab_events = 0
@@ -207,6 +221,8 @@ class SoADynamicDBSCAN:
         while cap < need:
             cap *= 2
         grow = cap - self._cap
+        if self._comp is not None:
+            self._drop_epoch()  # the epoch's arrays are sized by the rows
         self._ids = np.concatenate([self._ids, np.full(grow, -1, np.int64)])
         self._pts = np.concatenate(
             [self._pts, np.zeros((grow, self.d), np.float64)])
@@ -422,9 +438,25 @@ class SoADynamicDBSCAN:
             with tr.span("soa.insert.events"):
                 self._apply_insert_events(out, slots, staged, core_time,
                                           promoted_existing)
-            self._drop_epoch()
+            self._note_new_cores(rows, promoted_existing)
             self._compact_journal()
         return out
+
+    def _note_new_cores(self, rows: np.ndarray,
+                        promoted_existing: Dict[int, int]) -> None:
+        """Keep the epoch across an insert: record the rows that became
+        core, the batch's own and the promoted existing ones, for the next
+        query to merge (``_merge_epoch``).  Nothing is recorded while no
+        epoch is cached."""
+        if self._comp is None:
+            return
+        new = rows[self._support[rows] > 0]
+        if promoted_existing:
+            new = np.concatenate([new, np.fromiter(
+                map(self._row.__getitem__, promoted_existing), np.int64,
+                len(promoted_existing))])
+        if len(new):
+            self._pending.append(new)
 
     # ------------------------------------------------------------------ #
     # sampling hooks — the exact engine treats every point as core-
@@ -736,7 +768,7 @@ class SoADynamicDBSCAN:
     # ------------------------------------------------------------------ #
     def delete_point(self, idx: int) -> None:
         self._delete_one(idx)
-        self._comp = None
+        self._drop_epoch()
         self._compact_journal()
 
     def delete_batch(self, ids: Sequence[int]) -> None:
@@ -779,7 +811,8 @@ class SoADynamicDBSCAN:
         cached component array hands the rebuild's freed temporaries back
         to the allocator, which can cost as much as a phase."""
         with self.obs.tracer.span("soa.epoch.drop"):
-            self._comp = None
+            self._comp = self._slot_rep = None
+            self._pending = []
 
     def _departures(self, ids: List[int]):
         """The batch's array pass over the slots it touches: each point's
@@ -1001,27 +1034,86 @@ class SoADynamicDBSCAN:
         return a if a >= 0 else None
 
     def _ensure_comp(self) -> np.ndarray:
-        if self._comp is not None:
-            return self._comp
+        """The epoch: by row, the least core row of the row's component for
+        core rows, -1 for the rest; a component's handle is the id of its
+        least row.  Rebuilt in full when dropped, merged when inserts have
+        added core rows since."""
+        if self._comp is None:
+            self._rebuild_epoch()
+        elif self._pending:
+            self._merge_epoch()
+        return self._comp
+
+    def _rebuild_epoch(self) -> None:
         tr = self.obs.tracer
         with tr.span("soa.rebuild", on_device=self.use_device) as sp:
             if self.use_device:
-                comp, edges, rounds = self._device_comp()
+                comp, slot_rep, edges, rounds = self._device_comp()
             else:
-                comp, edges, rounds = self._host_comp()
+                comp, slot_rep, edges, rounds = self._host_comp()
             if self.obs.enabled:
                 sp.attrs.update(edges=edges, rounds=rounds)
                 self.obs.counter("soa.sv_rounds").inc(rounds)
                 if self.use_device:
                     self.obs.counter("soa.rebuild.device").inc()
-        self._comp = comp
+        self._comp, self._slot_rep, self._pending = comp, slot_rep, []
         self.n_epoch_rebuilds += 1
-        return comp
 
-    def _host_comp(self) -> Tuple[np.ndarray, int, int]:
+    def _merge_epoch(self) -> None:
+        """Fold the pending rows into the epoch on the host.  A pending row
+        joins the components of the core rows that share any of its slots,
+        and the pending rows that share a slot join each other: one
+        ``_sv_components`` over a compact graph whose nodes are the pending
+        rows' slots and the components those slots already belong to.  A
+        merged component's least row is the least of its parts' and of its
+        pending rows (freed rows are recycled, so a new row can be the
+        least); the rows of a component whose least row moved follow it
+        through one lookup over the row capacity."""
+        P = np.concatenate(self._pending)
+        self._pending = []
+        comp, t = self._comp, self.t
+        with self.obs.tracer.span("soa.epoch.merge", rows=len(P)) as sp:
+            rep = self._slot_rep
+            if len(rep) < len(self._bsize):  # slots opened since
+                rep = self._slot_rep = np.concatenate([rep, np.full(
+                    len(self._bsize) - len(rep), -1, np.int64)])
+            S = self._slots[P]
+            # nodes: the slots U, then the components V that hold them
+            U, inv = np.unique(S.ravel(), return_inverse=True)
+            inv = inv.reshape(S.shape)
+            r = rep[U]
+            held = np.nonzero(r >= 0)[0]
+            V, vinv = np.unique(comp[r[held]], return_inverse=True)
+            nu = len(U)
+            # edges: each row's first slot to its others, each held slot to
+            # its component
+            a = np.concatenate([np.repeat(inv[:, 0], t - 1), held])
+            b = np.concatenate([inv[:, 1:].ravel(), nu + vinv])
+            parent, _ = _sv_components(nu + len(V), a, b)
+            least = np.full(nu + len(V), self._cap, np.int64)
+            np.minimum.at(least, parent[inv[:, 0]], P)
+            np.minimum.at(least, parent[nu:], V)
+            root = least[parent]
+            moved = root[nu:] != V
+            if moved.any():
+                lut = np.arange(self._cap + 1, dtype=np.int64)
+                lut[-1] = -1  # non-core rows stay -1
+                lut[V[moved]] = root[nu:][moved]
+                comp = self._comp = lut[comp]
+            comp[P] = root[inv[:, 0]]
+            rep[U] = root[:nu]
+            if self.obs.enabled:
+                sp.attrs["nodes"] = len(V)
+                self.obs.counter("soa.epoch.merges").inc()
+                self.obs.counter("soa.epoch.merge_rows").inc(len(P))
+        self.n_epoch_merges += 1
+
+    def _host_comp(self) -> Tuple[np.ndarray, np.ndarray, int, int]:
         """The epoch on the host: chain edges between consecutive core
         rows of each slot, then ``_sv_components`` over the rows.  Returns
-        the component handles by row, the edge count and the rounds."""
+        the least core row of each core row's component by row, a core row
+        of each slot's component by slot (-1 for none), the edge count and
+        the rounds."""
         tr = self.obs.tracer
         with tr.span("soa.rebuild.edges"):
             rows = np.fromiter(self._row.values(), np.int64, len(self._row))
@@ -1038,17 +1130,19 @@ class SoADynamicDBSCAN:
         with tr.span("soa.rebuild.sv"):
             parent, rounds = _sv_components(self._top, a, b)
         comp = np.full(self._cap, -1, np.int64)
+        slot_rep = np.full(len(self._bsize), -1, np.int64)
         if len(core_rows):
-            comp[core_rows] = self._ids[parent[core_rows]]
-        return comp, len(a), rounds
+            comp[core_rows] = parent[core_rows]
+            slot_rep[sf] = parent[rf]
+        return comp, slot_rep, len(a), rounds
 
-    def _device_comp(self) -> Tuple[np.ndarray, int, int]:
+    def _device_comp(self) -> Tuple[np.ndarray, np.ndarray, int, int]:
         """The epoch as one device program over the bucket graph
         (``ops.core_components``): the slot matrix and the core mask go
-        in, each slot's least core row of its component comes back, and a
-        core row's handle is the id of that row for its first slot, the
-        same value the host path gives.  Returns the handles by row, the
-        (core row, slot) incidences and the rounds."""
+        in, each slot's least core row of its component comes back, and
+        each core row takes that of its first slot, the same row the host
+        path gives.  Returns what ``_host_comp`` does, with the (core row,
+        slot) incidences for the edges."""
         import jax
         import jax.numpy as jnp
 
@@ -1075,23 +1169,25 @@ class SoADynamicDBSCAN:
             with tr.span("soa.device.fetch"):
                 least, rounds = jax.device_get((least, rounds))
         comp = np.full(self._cap, -1, np.int64)
-        comp[core] = self._ids[least[slots[core, 0]]]
+        comp[core] = least[slots[core, 0]]
+        slot_rep = least[rank].astype(np.int64)
+        slot_rep[slot_rep == self._cap] = -1  # the program's "no core row"
         edges = 0
         if self.obs.enabled:
             self._count_copies((slots, core), (least, rounds))
             edges = int(np.count_nonzero(core)) * self.t
-        return comp, edges, int(rounds)
+        return comp, slot_rep, edges, int(rounds)
 
     def get_cluster(self, idx: int):
         """Component handle: the id of the component's representative core
         for cores and attached borders, the point's own id for noise."""
         r = self._row[idx]  # KeyError on dead ids, like forest.root
-        if self._support[r] > 0:
-            return int(self._ensure_comp()[r])
-        a = int(self._attach[r])
-        if a < 0:
-            return int(idx)
-        return int(self._ensure_comp()[self._row[a]])
+        if self._support[r] <= 0:
+            a = int(self._attach[r])
+            if a < 0:
+                return int(idx)
+            r = self._row[a]
+        return int(self._ids[self._ensure_comp()[r]])
 
     component_of = get_cluster
 
@@ -1106,7 +1202,7 @@ class SoADynamicDBSCAN:
         connecting cores were excluded.  Full ``labels()`` is identical.
         """
         id_list = list(self._row.keys()) if ids is None else list(ids)
-        comp = self._ensure_comp()
+        comp = self._ids[self._ensure_comp()]  # handles, read at core rows
         out: Dict[int, int] = {}
         relabel: Dict[int, int] = {}
         for v in id_list:
@@ -1253,7 +1349,7 @@ class SoADynamicDBSCAN:
             if a >= 0:
                 self.anchored.setdefault(a, set()).add(i)
         self._next_idx = int(state["next_idx"])
-        self._comp = None
+        self._drop_epoch()
 
     def _rebuild_support(self, slots: np.ndarray,
                          ids: List[int]) -> np.ndarray:

@@ -300,11 +300,20 @@ def test_device_epoch_matches_host_epoch(case, orphans):
         assert dev.labels() == host.labels()
         assert dev.drain_deltas() == host.drain_deltas()
         caps.append((dev.engine._cap, len(dev.engine._bsize)))
-    assert dev.engine.n_epoch_rebuilds == len(caps)
+    # an epoch after each step: rebuilt after a delete or a row-capacity
+    # growth, merged after an insert into a cached epoch
+    for ix in (host, dev):
+        assert (ix.engine.n_epoch_rebuilds + ix.engine.n_epoch_merges
+                == len(caps))
     rounds = [s["args"]["rounds"] for s in dev.obs.tracer.export()
               if s["name"] == "soa.rebuild"]
     assert all(s["args"]["on_device"] for s in dev.obs.tracer.export()
                if s["name"] == "soa.rebuild")
+    # the device program over the final state gives the epoch's rows,
+    # whether the last step rebuilt or merged it
+    full, _, _, last = dev.engine._device_comp()
+    assert np.array_equal(full, dev.engine._ensure_comp())
+    rounds.append(last)
     labels = dev.labels()
     if case == "split":
         # two chains, then the first cut in two
@@ -315,6 +324,125 @@ def test_device_epoch_matches_host_epoch(case, orphans):
         assert rounds[-1] == 0 and set(labels.values()) == {NOISE}
     else:
         assert caps[0] == (256, 256) and caps[1][0] > 256 < caps[1][1]
+
+
+# ---------------------------------------------------------------------- #
+# the epoch kept across inserts: merged, equal to a full rebuild
+# ---------------------------------------------------------------------- #
+def _epoch_handles(ix):
+    """Handles by row (-1 off the core rows) of the epoch that ``label()``
+    reads, merged if inserts came since it was computed, and of a full
+    rebuild of the same state on the engine's own path."""
+    eng = ix.engine
+    got = eng._ensure_comp()
+    full = (eng._device_comp if eng.use_device else eng._host_comp)()[0]
+    return [np.where(c >= 0, eng._ids[c], -1) for c in (got, full)]
+
+
+def _clumps(pos):
+    """K points at each position times EPS along the x axis (``_chain``'s
+    clumps, in the order given)."""
+    X = np.zeros((len(pos) * K, 2))
+    X[:, 0] = np.repeat(np.asarray(pos, float) * EPS, K)
+    return X
+
+
+def _core_handles(ix, ids):
+    return {ix.label(i) for i in ids if ix.is_core(i)}
+
+
+# the stream's steps: insert the next batch, read, expire the oldest
+# batch, restore from a snapshot
+MERGE_STREAM = "I I R I R I I R I R E R I R S R I I R E I R I R"
+
+
+def _merge_stream(ix, rng):
+    """A sliding window over sparse, noisy batches: up to two inserts
+    between reads, expiries, row-capacity growth, and a restore from a
+    snapshot.  Yields the index at each read, with whether the rows it
+    merges hold one that was live before its batch (a promotion)."""
+    X = _mixture(150 * MERGE_STREAM.count("I"), SPARSE["d"],
+                 seed=int(rng.integers(1 << 30)))
+    alive: list = []
+    lo, before = 0, np.zeros(0, np.int64)
+    for op in MERGE_STREAM.split():
+        if op == "I":
+            before = np.asarray(alive, np.int64)
+            alive += ix.insert_batch(X[lo:lo + 150])
+            lo += 150
+        elif op == "E":
+            ix.delete_batch(alive[:150])
+            alive = alive[150:]
+        elif op == "S":
+            ix = restore_index(ix.snapshot())
+        else:
+            eng = ix.engine
+            pend = (np.concatenate(eng._pending) if eng._pending
+                    else np.zeros(0, np.int64))
+            yield ix, bool(np.isin(eng._ids[pend], before).any())
+
+
+@pytest.mark.parametrize("orphans", [True, False])
+@pytest.mark.parametrize("case", ["stream", "bridge", "recycled"])
+@pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
+def test_merged_epoch_matches_full_rebuild(backend, case, orphans):
+    """After inserts into a cached epoch, the first read merges the new
+    core rows into it; its handles, by row, equal a full rebuild's.
+    ``stream``: noisy batches whose crossings promote existing rows, two
+    inserts between some reads, expiries, capacity growth and a restore;
+    ``bridge``: one clump joins two chains; ``recycled``: a clump lands in
+    rows freed below a chain's least row, so the chain's handle moves."""
+    if case == "stream":
+        cfg = ClusterConfig(**SPARSE, backend=backend, attach_orphans=orphans,
+                            sample_rate=0.5 if backend == "approx" else 1.0)
+        merges = promoted = rebuilds = 0
+        for ix, old_row_merged in _merge_stream(build_index(cfg),
+                                                np.random.default_rng(3)):
+            merged = ix.engine.n_epoch_merges
+            rebuilt = ix.engine.n_epoch_rebuilds
+            got, full = _epoch_handles(ix)
+            assert np.array_equal(got, full)
+            merges += ix.engine.n_epoch_merges - merged
+            rebuilds += ix.engine.n_epoch_rebuilds - rebuilt
+            promoted += old_row_merged
+            ix.check_invariants()
+        assert merges >= 3 and rebuilds >= 3 and promoted >= 1
+        return
+    ix = build_index(ClusterConfig(d=2, k=K, t=T, eps=EPS, seed=1,
+                                   backend=backend, attach_orphans=orphans))
+    if case == "bridge":
+        left = ix.insert_batch(_clumps(range(10)))
+        assert len(_core_handles(ix, left)) == 1
+        # a second chain, all in slots that held no core row: a merge
+        right = ix.insert_batch(_clumps(range(11, 21)))
+        assert len(_core_handles(ix, left + right)) == 2
+        got, full = _epoch_handles(ix)
+        assert np.array_equal(got, full)
+        # and the clump between them, which the second merge finds in
+        # slots the first one filled
+        ix.insert_batch(_clumps([10]))
+        merges = 2
+    else:
+        far = np.zeros((20, 2))
+        far[:, 1] = np.arange(1, 21) * 10 * EPS  # one point a bucket
+        noise = ix.insert_batch(far)
+        chain = ix.insert_batch(_clumps(range(10)))
+        (old,) = _core_handles(ix, chain)
+        ix.delete_batch(noise)
+        assert _core_handles(ix, chain) == {old}
+        added = ix.insert_batch(_clumps([10]))  # rows from the freed ones
+        merges = 1
+    assert ix.engine._pending
+    got, full = _epoch_handles(ix)
+    assert np.array_equal(got, full)
+    assert ix.engine.n_epoch_merges == merges
+    handles = _core_handles(ix, ix.ids())
+    assert len(handles) == 1
+    if case == "recycled":
+        # the least row is now a recycled one, below the chain's old least
+        (new,) = handles
+        assert new in added and ix.engine._row[new] < ix.engine._row[old]
+    ix.check_invariants()
 
 
 # ---------------------------------------------------------------------- #

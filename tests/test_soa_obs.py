@@ -36,7 +36,6 @@ INSERT = {("soa.insert", None)} | {
     for p in ("claim", "hash", "resolve_slots", "batch_stats", "crossings",
               "members", "commit", "events")} | {
     ("soa.journal.compact", "soa.insert"),
-    ("soa.epoch.drop", "soa.insert"),
     ("soa.device.fetch", "soa.insert.hash"),
     ("soa.device.fetch", "soa.insert.batch_stats")}
 EXPIRE = {("soa.expire", None), ("soa.journal.compact", "soa.expire"),
@@ -166,6 +165,59 @@ def test_host_engine_rebuilds_on_the_host():
     assert m["soa.sv_rounds"]["value"] == sum(
         s["args"]["rounds"] for s in rebuilds)
     assert set(m) == {"soa.sv_rounds"} | set(REPLAY)
+
+
+# what a merge of the epoch counts: merges served, rows folded in
+MERGE = ("soa.epoch.merges", "soa.epoch.merge_rows")
+
+
+@pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
+def test_insert_after_a_read_merges_and_expire_rebuilds(backend):
+    """insert / labels / insert / labels rebuilds the epoch once and merges
+    it once, in a span beside ``soa.rebuild``; an expiry drops it, and the
+    next read rebuilds in full.  The counters count the merge that ran."""
+    ix = build_index(cfg(backend))
+    X = points(400)
+    ids = ix.insert_batch(X[:300])  # capacity 512: the next insert fits
+    ix.labels()
+    ids += ix.insert_batch(X[300:])
+    rows = sum(map(len, ix.engine._pending))
+    assert rows > 0
+    ix.labels()
+    ix.label(ids[-1])  # the merged epoch answers without another span
+    spans = ix.obs.tracer.drain_export()
+    got = tree(spans)
+    assert got.count(("soa.rebuild", None)) == 1
+    assert got.count(("soa.epoch.merge", None)) == 1
+    assert not any(parent == "soa.epoch.merge" for _, parent in got)
+    (merge,) = [s for s in spans if s["name"] == "soa.epoch.merge"]
+    assert merge["args"]["rows"] == rows and merge["args"]["nodes"] >= 1
+    m = ix.obs.snapshot()["metrics"]
+    assert m["soa.epoch.merges"]["value"] == 1
+    assert m["soa.epoch.merge_rows"]["value"] == rows
+    assert ix.stats()["n_epoch_merges"] == ix.engine.n_epoch_merges == 1
+    assert ix.stats()["n_epoch_rebuilds"] == ix.engine.n_epoch_rebuilds == 1
+
+    ix.delete_batch(ids[:50])
+    ix.labels()
+    got = tree(ix.obs.tracer.drain_export())
+    assert got.count(("soa.rebuild", None)) == 1
+    assert ("soa.epoch.merge", None) not in got
+    assert ix.obs.snapshot()["metrics"]["soa.epoch.merges"]["value"] == 1
+    assert ix.engine.n_epoch_rebuilds == 2
+
+
+def test_insert_without_a_cached_epoch_records_nothing():
+    """With no epoch cached, as in a stream that never reads, an insert
+    keeps nothing for a merge and no merge counter exists."""
+    ix = build_index(cfg())
+    X = points(400)
+    ix.insert_batch(X[:200])
+    ix.insert_batch(X[200:])
+    assert ix.engine._comp is None and not ix.engine._pending
+    ix.labels()
+    assert ix.engine.n_epoch_rebuilds == 1 and ix.engine.n_epoch_merges == 0
+    assert not set(MERGE) & set(ix.obs.snapshot()["metrics"])
 
 
 @pytest.mark.parametrize("backend", ["soa", "soa-device", "approx"])
